@@ -65,9 +65,9 @@ serve:
 # multi-chip mesh smoke (README "Multi-chip"): the doc-sharded MeshFarm
 # on 8 forced virtual CPU host devices — fan-out, mid-run page-granular
 # migration, actor-table reconcile convergence, ownership audit; gates
-# are machine-independent. The full MULTICHIP record run (8192 docs,
-# real devices when present): `python bench.py --mesh`; also a tier-1
-# test (tests/test_mesh_smoke.py)
+# are machine-independent. The full MULTICHIP record run (8192 docs;
+# needs the chips, fails without them): `python bench.py --mesh`; also a
+# tier-1 test (tests/test_mesh_smoke.py)
 mesh:
 	$(PY) bench.py --mesh --quick
 

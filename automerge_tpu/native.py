@@ -2,10 +2,10 @@
 
 The native library accelerates the host-side transcoding between the
 variable-length column formats and dense numpy arrays (the input/output of
-the TPU engine). Falls back to the pure-Python codecs when the library has
-not been built; `available()` reports which path is active.
-
-Build with: make -C native   (or python -m automerge_tpu.native --build)
+the TPU engine). The library is not committed: the first load builds it
+from codecs.cpp with ``make -C native`` (and rebuilds it when the source is
+newer). Falls back to the pure-Python codecs when it cannot be built;
+`available()` reports which path is active.
 """
 # amlint: host-only — pure-host layer: must not import tpu/ or jax
 from __future__ import annotations
@@ -18,15 +18,46 @@ import numpy as np
 
 NULL_SENTINEL = -(2**62)
 
-_LIB_PATH = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                         "native", "libamcodecs.so")
+_NATIVE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "native"
+)
+_LIB_PATH = os.path.join(_NATIVE_DIR, "libamcodecs.so")
+_SRC_PATH = os.path.join(_NATIVE_DIR, "codecs.cpp")
 _lib = None
+_build_tried = False
+
+
+def _stale() -> bool:
+    """True when the library is missing or older than its source."""
+    if not os.path.exists(_LIB_PATH):
+        return True
+    return (os.path.exists(_SRC_PATH)
+            and os.path.getmtime(_LIB_PATH) < os.path.getmtime(_SRC_PATH))
+
+
+def _build() -> None:
+    """Builds the library into a temp name and renames it into place, so
+    processes building at once (the xdist workers) never load a
+    half-written file. A failed build leaves the pure-Python codecs on."""
+    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    try:
+        done = subprocess.run(["make", "-C", _NATIVE_DIR, f"LIB={tmp}"],
+                              capture_output=True, text=True)
+    except OSError:  # no make on this host
+        return
+    if done.returncode == 0:
+        os.replace(tmp, _LIB_PATH)
+    elif os.path.exists(tmp):
+        os.unlink(tmp)
 
 
 def _load():
-    global _lib
+    global _lib, _build_tried
     if _lib is not None:
         return _lib
+    if not _build_tried and _stale():
+        _build_tried = True
+        _build()
     if not os.path.exists(_LIB_PATH):
         return None
     lib = ctypes.CDLL(_LIB_PATH)
@@ -54,18 +85,6 @@ def _load():
                                          ctypes.c_size_t, i64p, ctypes.c_size_t]
     _lib = lib
     return lib
-
-
-def build(verbose=False):
-    """Compiles the native library with g++."""
-    native_dir = os.path.dirname(_LIB_PATH)
-    result = subprocess.run(["make", "-C", native_dir],
-                            capture_output=not verbose, text=True)
-    if result.returncode != 0:
-        raise RuntimeError(f"native build failed: {result.stderr}")
-    global _lib
-    _lib = None
-    return _load() is not None
 
 
 def available() -> bool:
@@ -174,8 +193,5 @@ def bool_encode(values: np.ndarray) -> bytes:
 
 
 if __name__ == "__main__":
-    import sys
-
-    if "--build" in sys.argv:
-        ok = build(verbose=True)
-        print("native codecs built" if ok else "build failed")
+    print("native codecs active" if available() else
+          "native codecs unavailable (make -C native failed)")

@@ -576,6 +576,18 @@ class MeshFarm:
                 f"mesh_backend must be 'inline' or 'process', "
                 f"got {mesh_backend!r}"
             )
+        if mesh_backend == "process":
+            import jax
+
+            if jax.default_backend() != "cpu":
+                # amlint: disable=AM401 — API-usage validation, not a
+                # data-plane fault (nothing was decoded or dispatched)
+                raise ValueError(
+                    "mesh_backend='process' runs on the CPU only: a chip "
+                    "belongs to one process and workers are not pinned to "
+                    "chips yet; use mesh_backend='inline' (one process "
+                    "driving every chip)"
+                )
         if mesh_transport is None:
             mesh_transport = os.environ.get("AM_MESH_TRANSPORT", "auto")
         if mesh_transport not in ("auto", "pickle", "shm"):

@@ -135,7 +135,10 @@ def _worker_main(conn, spec: dict) -> None:
     from ..obs.flight import get_flight, write_blackbox
     from ..obs.scope import exemplar_context
     from ..profiling import PhaseProfile, use_profile
+    from ..tpu.compile_cache import enable_compile_cache
     from ..tpu.farm import TpuDocFarm, exc_from_blob, exc_to_blob, result_to_wire
+
+    enable_compile_cache()
 
     metrics = get_metrics()  # amlint: disable=AM502 — same shipping buffer
     metrics.enable()

@@ -586,16 +586,17 @@ def leb128_scan_device(data, *, interpret: bool | None = None):
     if int(jax.device_get(lengths.max())) > 8:
         raise _Fallback("varint wider than 8 bytes")
     pos = jnp.arange(n) - starts[seg]
-    contrib = (data & 0x7F).astype(jnp.int64) << (7 * pos)
-    # 14-bit planes keep every f32 one-hot product exact in the kernel
-    planes = jnp.stack(
-        [(contrib >> (14 * k)) & 0x3FFF for k in range(4)], axis=1
-    ).astype(jnp.float32)
+    # one 7-bit plane per byte position: every one-hot product in the
+    # kernel is a byte, exact in bf16
+    payload = (data & 0x7F).astype(jnp.float32)
+    planes = jnp.where(
+        pos[:, None] == jnp.arange(8)[None, :], payload[:, None], 0.0
+    )
     sums = leb128_segment_sum(
         planes, seg.astype(jnp.int32), nvar, interpret=interpret
     )
     unsigned = sum(
-        sums[:, k].astype(jnp.int64) << (14 * k) for k in range(4)
+        sums[:, k].astype(jnp.int64) << (7 * k) for k in range(8)
     )
     sign = (data[ends] & 0x40) != 0
     signed = unsigned - (sign.astype(jnp.int64) << (7 * lengths))

@@ -6,16 +6,25 @@ addHash:107). At replica-farm scale that is B filters x C candidates x 7
 probes of bit tests — a bandwidth-bound bitwise workload that XLA executes
 as a chain of gathers. These kernels fuse the whole probe sequence in VMEM:
 
-- probe positions are computed with the reference's triple-hashing recurrence
-  (x += y; y += z, all mod filter size) unrolled NUM_PROBES times;
-- the word gather `words[probe >> 5]` is expressed as a one-hot matmul so it
-  rides the MXU instead of serialising into scalar gathers. uint32 words are
-  split into two uint16 halves so the f32 matmul is exact (one-hot rows sum
-  a single term < 2^16);
+- probe positions follow the reference's triple-hashing recurrence
+  (x += y; y += z, all mod filter size) unrolled NUM_PROBES times. The
+  wrapper reduces x, y, z mod the filter size once in XLA, so the kernel
+  needs only add/compare/subtract;
+- the word gather `words[probe >> 5]` and the build's OR-scatter ride the
+  MXU as one-hot contractions. Every operand is a 0/1 one-hot or a byte
+  (uint32 words travel as four byte planes), so each product is exact in
+  bf16 with f32 accumulation, whatever precision the MXU runs at;
+- hashes and words are lane-major rows (``[3, N]``, ``[1, W]``) and the
+  per-filter scalars ride scalar prefetch, so every block is (8, 128)-
+  aligned or full-dim, as Mosaic requires;
 - the grid tiles the entry/query axis and the word axis, OR-accumulating
-  into revisited output blocks, so every VMEM block stays a few MB no matter
-  how large the filter or candidate set grows (a 10k-change filter is ~3200
-  words; one-shot one-hots over that would be ~1 GB).
+  into revisited output blocks, so every VMEM block stays small no matter
+  how large the filter or candidate set grows.
+
+Index maps and in-kernel fill values are ``jnp.int32`` literals: under the
+package-wide ``jax_enable_x64`` a bare Python int lowers to i64, and Mosaic
+refuses an index map that returns (i32, i64) and recurses converting a
+weak i64 fill.
 
 On CPU the kernels run in the Pallas interpreter (tests); on TPU they are
 compiled. Results are bit-identical to the XLA reference implementations in
@@ -35,78 +44,93 @@ from .jitprof import profiled_jit
 
 WORD_BITS = 32
 _LANES = 128
-# VMEM budgets: the one-hot intermediates are [P, ENTRY/QUERY_TILE, WORD_TILE]
-# f32 — 7 * 256 * 512 * 4 B = 3.5 MB, comfortably under ~16 MB VMEM.
+# VMEM budgets: the largest intermediate is one probe's bf16 one-hot,
+# [WORD_TILE, ENTRY/QUERY_TILE] = 512 * 256 * 2 B = 256 KB.
 _ENTRY_TILE = 256
 _QUERY_TILE = 256
 _WORD_TILE = 512
+_NT = (((1,), (1,)), ((), ()))  # dot_general: contract both operands' lanes
 
 
 def _pad_to(n: int, m: int) -> int:
     return ((n + m - 1) // m) * m or m
 
 
+def _zero():
+    """An i32 index-map literal (see module docstring)."""
+    return jnp.int32(0)
+
+
+def _reduced_rows(xyz, modulo, n_pad):
+    """[B, N, 3] uint32 -> [B, 3, n_pad] uint32 holding xyz % modulo, one
+    lane-major row per hash word (pad columns are zero)."""
+    m = jnp.maximum(modulo, 1).astype(jnp.uint32)[:, None, None]
+    rows = jnp.transpose(xyz % m, (0, 2, 1))
+    return jnp.pad(rows, ((0, 0), (0, 0), (0, n_pad - xyz.shape[1])))
+
+
 def _probe_rows(xyz, modulo):
-    """Unrolled triple-hash probe positions. xyz: [C, 3] uint32, modulo
-    scalar uint32. Returns [NUM_PROBES, C] uint32."""
-    modulo = jnp.maximum(modulo, jnp.uint32(1))
-    x = xyz[:, 0] % modulo
-    y = xyz[:, 1] % modulo
-    z = xyz[:, 2] % modulo
+    """Triple-hash probe positions. xyz: [3, N] uint32 already reduced mod
+    `modulo` (uint32 scalar). Returns NUM_PROBES rows of [1, N] uint32.
+    x + y < 2 * modulo < 2^32, so the conditional subtract is the exact
+    `% modulo` of the reference."""
+    x, y, z = xyz[0:1, :], xyz[1:2, :], xyz[2:3, :]
     rows = [x]
     for _ in range(NUM_PROBES - 1):
-        x = (x + y) % modulo
-        y = (y + z) % modulo
+        x = x + y
+        x = jnp.where(x >= modulo, x - modulo, x)
+        y = y + z
+        y = jnp.where(y >= modulo, y - modulo, y)
         rows.append(x)
-    return jnp.stack(rows)
+    return rows
 
 
-def _gather_words_mxu(words_u32, word_idx, num_words):
-    """words[word_idx] as a one-hot MXU contraction.
-
-    words_u32: [W] uint32, word_idx: [P, C] int32 (must be in [0, W)) ->
-    [P, C] uint32. The one-hot rows select exactly one element, and uint16
-    halves keep every f32 product exactly representable."""
-    lo = (words_u32 & jnp.uint32(0xFFFF)).astype(jnp.float32)  # [W]
-    hi = (words_u32 >> 16).astype(jnp.float32)
-    onehot = (word_idx[..., None] == jnp.arange(num_words, dtype=jnp.int32)).astype(
-        jnp.float32
-    )  # [P, C, W]
-    g_lo = jnp.einsum("pcw,w->pc", onehot, lo, preferred_element_type=jnp.float32)
-    g_hi = jnp.einsum("pcw,w->pc", onehot, hi, preferred_element_type=jnp.float32)
-    return g_lo.astype(jnp.uint32) | (g_hi.astype(jnp.uint32) << 16)
+def _join_bytes(planes):
+    """[4, N] int32 byte rows -> [1, N] int32 word (uint32 bit pattern)."""
+    return (planes[0:1] | (planes[1:2] << 8) | (planes[2:3] << 16)
+            | (planes[3:4] << 24))
 
 
-def _bloom_query_kernel(words_ref, modulo_ref, xyz_ref, out_ref, *, num_words):
-    """One (filter, query-tile, word-tile) cell. Blocks: words [1, W_T],
-    modulo [1, 1] (SMEM), xyz [1, C_T, 3], out [1, P, C_T] int32 holding the
-    probed bit per (probe, query), OR-accumulated across word tiles (each
-    probe's word lives in exactly one tile, so the OR is exact). word_idx is
+def _bloom_query_kernel(modulo_ref, words_ref, xyz_ref, out_ref, *, num_words):
+    """One (filter, query-tile, word-tile) cell. Blocks: words [1, W_T]
+    int32, xyz [3, C_T] uint32, out [P, C_T] int32 holding the probed bit
+    per (probe, query), OR-accumulated across word tiles (each probe's
+    word lives in exactly one tile, so the OR is exact). word_idx is
     clamped to num_words - 1 exactly like sync_batch.query_filters' gather,
     keeping the two implementations bit-identical even for over-sized moduli
     (possible only when a caller undersizes num_words for the filter count)."""
     w_idx = pl.program_id(2)
     w_t = words_ref.shape[1]
-    modulo = modulo_ref[0, 0].astype(jnp.uint32)
-    probes = _probe_rows(xyz_ref[0], modulo)  # [P, C_T]
-    word_idx = jnp.minimum((probes // WORD_BITS).astype(jnp.int32), num_words - 1)
-    bit_idx = probes % WORD_BITS
-    local = word_idx - w_idx * w_t
-    in_tile = (local >= 0) & (local < w_t)
-    gathered = _gather_words_mxu(
-        words_ref[0], jnp.where(in_tile, local, 0), w_t
-    )
-    bit_set = jnp.where(in_tile, (gathered >> bit_idx) & jnp.uint32(1), 0).astype(
-        jnp.int32
-    )
+    c_t = xyz_ref.shape[1]
+    modulo = jnp.maximum(modulo_ref[pl.program_id(0)], 1).astype(jnp.uint32)
+    words = words_ref[...]
+    planes = jnp.concatenate(
+        [(words >> (8 * k)) & 0xFF for k in range(4)], axis=0
+    ).astype(jnp.bfloat16)  # [4, W_T]
+    lanes = jax.lax.broadcasted_iota(jnp.int32, (w_t, c_t), 0)
+    rows = _probe_rows(xyz_ref[...], modulo)
+    bits = []
+    for p in range(NUM_PROBES):
+        probe = rows[p]
+        word_idx = jnp.minimum(
+            (probe >> 5).astype(jnp.int32), num_words - 1
+        )
+        local = word_idx - w_idx * w_t  # [1, C_T]; off-tile never matches
+        onehot = (lanes == local).astype(jnp.bfloat16)  # [W_T, C_T]
+        gathered = _join_bytes(jnp.dot(
+            planes, onehot, preferred_element_type=jnp.float32
+        ).astype(jnp.int32))
+        bit_idx = (probe & 31).astype(jnp.int32)
+        bits.append(jax.lax.shift_right_logical(gathered, bit_idx) & 1)
+    bits = jnp.concatenate(bits, axis=0)  # [P, C_T]
 
     @pl.when(w_idx == 0)
     def _init():
-        out_ref[0] = bit_set
+        out_ref[...] = bits
 
     @pl.when(w_idx > 0)
     def _accumulate():
-        out_ref[0] = out_ref[0] | bit_set
+        out_ref[...] = out_ref[...] | bits
 
 
 @profiled_jit("pallas.bloom_query", static_argnames=("interpret",))
@@ -121,67 +145,117 @@ def bloom_query(words, modulo, counts, query_xyz, *, interpret=False):
     c_t = min(_pad_to(c, _LANES), _QUERY_TILE)
     w_pad = _pad_to(num_words, w_t)
     c_pad = _pad_to(c, c_t)
-    words = jnp.pad(words, ((0, 0), (0, w_pad - num_words)))
-    query_xyz = jnp.pad(query_xyz, ((0, 0), (0, c_pad - c), (0, 0)))
+    words = jnp.pad(
+        jax.lax.bitcast_convert_type(words, jnp.int32),
+        ((0, 0), (0, w_pad - num_words)),
+    ).reshape(batch, 1, w_pad)
+    rows = _reduced_rows(query_xyz, modulo, c_pad)
 
     bits = pl.pallas_call(
         partial(_bloom_query_kernel, num_words=num_words),
-        grid=(batch, c_pad // c_t, w_pad // w_t),
-        in_specs=[
-            pl.BlockSpec((1, w_t), lambda b, q, w: (b, w), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1), lambda b, q, w: (b, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec(
-                (1, c_t, 3), lambda b, q, w: (b, q, 0), memory_space=pltpu.VMEM
-            ),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, NUM_PROBES, c_t), lambda b, q, w: (b, 0, q), memory_space=pltpu.VMEM
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(batch, c_pad // c_t, w_pad // w_t),
+            in_specs=[
+                pl.BlockSpec((None, 1, w_t),
+                             lambda b, q, w, m: (b, _zero(), w)),
+                pl.BlockSpec((None, 3, c_t),
+                             lambda b, q, w, m: (b, _zero(), q)),
+            ],
+            out_specs=pl.BlockSpec((None, NUM_PROBES, c_t),
+                                   lambda b, q, w, m: (b, _zero(), q)),
         ),
         out_shape=jax.ShapeDtypeStruct((batch, NUM_PROBES, c_pad), jnp.int32),
         interpret=interpret,
-    )(
-        words,
-        modulo.reshape(batch, 1).astype(jnp.int32),
-        query_xyz,
-    )
+    )(modulo.astype(jnp.int32), words, rows)
     all_set = jnp.min(bits[:, :, :c], axis=1)
     return jnp.where(counts[:, None] > 0, all_set, 0).astype(jnp.bool_)
 
 
-def _bloom_build_kernel(xyz_ref, modulo_ref, count_ref, out_ref):
-    """One (filter, word-tile, entry-tile) cell. Blocks: xyz [1, E_T, 3],
-    modulo/count [1, 1] (SMEM), out words [1, W_T] int32, OR-accumulated
-    across entry tiles (the innermost grid axis, so the block is revisited
-    consecutively)."""
+def _bloom_build_kernel(modulo_ref, count_ref, xyz_ref, out_ref):
+    """One (filter, word-tile, entry-tile) cell. Blocks: xyz [3, E_T]
+    uint32, out words [1, W_T] int32, OR-accumulated across entry tiles
+    (the innermost grid axis, so the block is revisited consecutively).
+    Per probe, ``hit[w, e] . bit[k, e]^T`` counts the entries that set bit
+    k of word w; a second contraction folds the set bits into byte rows."""
+    b = pl.program_id(0)
     w_idx = pl.program_id(1)
     e_idx = pl.program_id(2)
     e_t = xyz_ref.shape[1]
     w_t = out_ref.shape[1]
-    modulo = modulo_ref[0, 0].astype(jnp.uint32)
-    count = count_ref[0, 0]
-    probes = _probe_rows(xyz_ref[0], modulo)  # [P, E_T]
-    word_idx = (probes // WORD_BITS).astype(jnp.int32)
-    bit = jnp.uint32(1) << (probes % WORD_BITS)
-    global_e = e_idx * e_t + jax.lax.broadcasted_iota(
-        jnp.int32, (NUM_PROBES, e_t), 1
-    )
-    entry_ok = global_e < count
-    # OR-accumulate per word without scatters: for each word lane w of this
-    # tile, fold together the bits of every probe that lands in w.
-    local = word_idx - w_idx * w_t
-    hit = (local[..., None] == jnp.arange(w_t, dtype=jnp.int32)) & entry_ok[..., None]
-    contrib = jnp.where(hit, bit[..., None], jnp.uint32(0))
-    words = jax.lax.reduce(
-        contrib, jnp.uint32(0), jax.lax.bitwise_or, dimensions=(0, 1)
-    ).astype(jnp.int32)  # [W_T]
+    modulo = jnp.maximum(modulo_ref[b], 1).astype(jnp.uint32)
+    global_e = e_idx * e_t + jax.lax.broadcasted_iota(jnp.int32, (1, e_t), 1)
+    entry_ok = global_e < count_ref[b]
+    word_lanes = jax.lax.broadcasted_iota(jnp.int32, (w_t, e_t), 0)
+    bit_lanes = jax.lax.broadcasted_iota(jnp.int32, (WORD_BITS, e_t), 0)
+    rows = _probe_rows(xyz_ref[...], modulo)
+    counts = jnp.zeros((w_t, WORD_BITS), jnp.float32)
+    for p in range(NUM_PROBES):
+        probe = rows[p]
+        local = jnp.where(
+            entry_ok, (probe >> 5).astype(jnp.int32) - w_idx * w_t,
+            jnp.int32(-1),
+        )
+        hit = (word_lanes == local).astype(jnp.bfloat16)  # [W_T, E_T]
+        bit = (bit_lanes == (probe & 31).astype(jnp.int32)).astype(
+            jnp.bfloat16
+        )  # [32, E_T]
+        counts = counts + jax.lax.dot_general(
+            hit, bit, _NT, preferred_element_type=jnp.float32
+        )
+    present = (counts > 0).astype(jnp.bfloat16)  # [W_T, 32]
+    k = jax.lax.broadcasted_iota(jnp.int32, (4, WORD_BITS), 1)
+    byte = jax.lax.broadcasted_iota(jnp.int32, (4, WORD_BITS), 0)
+    weights = jnp.where(k >> 3 == byte, jnp.ones_like(k) << (k & 7),
+                        jnp.zeros_like(k)).astype(jnp.bfloat16)
+    words = _join_bytes(jax.lax.dot_general(
+        weights, present, _NT, preferred_element_type=jnp.float32
+    ).astype(jnp.int32))  # [1, W_T]
 
     @pl.when(e_idx == 0)
     def _init():
-        out_ref[0, :] = words
+        out_ref[...] = words
 
     @pl.when(e_idx > 0)
     def _accumulate():
-        out_ref[0, :] = out_ref[0, :] | words
+        out_ref[...] = out_ref[...] | words
+
+
+@profiled_jit("pallas.bloom_build", static_argnames=("num_words", "interpret"))
+def bloom_build(xyz, counts, num_words: int, *, interpret=False):
+    """Pallas analogue of sync_batch.build_filters.
+
+    xyz: [B, E, 3] uint32, counts: [B] int32. Returns (words [B, num_words]
+    uint32, modulo [B] int32) exactly like sync_batch.build_filters."""
+    from .sync_batch import filter_modulo
+
+    batch, e, _ = xyz.shape
+    modulo = filter_modulo(counts)
+    e_t = min(_pad_to(e, _LANES), _ENTRY_TILE)
+    w_t = min(_pad_to(num_words, _LANES), _WORD_TILE)
+    e_pad = _pad_to(e, e_t)
+    w_pad = _pad_to(num_words, w_t)
+    rows = _reduced_rows(xyz, modulo, e_pad)
+
+    words = pl.pallas_call(
+        _bloom_build_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(batch, w_pad // w_t, e_pad // e_t),
+            in_specs=[
+                pl.BlockSpec((None, 3, e_t),
+                             lambda b, w, ei, m, n: (b, _zero(), ei)),
+            ],
+            out_specs=pl.BlockSpec((None, 1, w_t),
+                                   lambda b, w, ei, m, n: (b, _zero(), w)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((batch, 1, w_pad), jnp.int32),
+        interpret=interpret,
+    )(modulo.astype(jnp.int32), counts.astype(jnp.int32), rows)
+    words = jax.lax.bitcast_convert_type(
+        words[:, 0, :num_words], jnp.uint32
+    )
+    return words, modulo
 
 
 _SEG_TILE = 128
@@ -191,11 +265,11 @@ _BYTE_TILE = 512
 def _leb_segsum_kernel(planes_ref, seg_ref, out_ref):
     """One (varint-tile, byte-tile) cell of the LEB128 segmented sum.
 
-    Blocks: planes [B_T, P] f32 (14-bit payload planes per byte), seg
+    Blocks: planes [B_T, P] f32 (7-bit payload planes per byte), seg
     [B_T, 1] int32 (varint id per byte, -1 for padding), out [V_T, P] f32.
     Each byte belongs to exactly one varint, so accumulating partial
     one-hot matmuls over byte tiles reconstructs the exact per-varint
-    plane sums (every product is an integer < 2^17, exact in f32)."""
+    plane sums (every operand is below 2^8, exact in bf16)."""
     v_idx = pl.program_id(1)
     b_idx = pl.program_id(2)
     v_t = out_ref.shape[0]
@@ -203,9 +277,10 @@ def _leb_segsum_kernel(planes_ref, seg_ref, out_ref):
     local = seg - v_idx * v_t
     onehot = (
         jax.lax.broadcasted_iota(jnp.int32, (v_t, seg.shape[0]), 0) == local[None, :]
-    ).astype(jnp.float32)  # [V_T, B_T]
+    ).astype(jnp.bfloat16)  # [V_T, B_T]
     partial_sums = jnp.dot(
-        onehot, planes_ref[...], preferred_element_type=jnp.float32
+        onehot, planes_ref[...].astype(jnp.bfloat16),
+        preferred_element_type=jnp.float32,
     )
 
     @pl.when(b_idx == 0)
@@ -224,10 +299,10 @@ def leb128_segment_sum(planes, seg_ids, num_segments: int, *, interpret=False):
     (tpu/decode.leb128_scan_device): ``out[v, p] = sum(planes[i, p] for i
     with seg_ids[i] == v)``.
 
-    planes: [N, P] f32, seg_ids: [N] int32 in [0, num_segments). XLA
-    lowers this reduction to serialised scatters on TPU; here it rides the
-    MXU as a tiled one-hot contraction, the same pattern as the Bloom
-    word gather above."""
+    planes: [N, P] f32 with integer entries below 2^8, seg_ids: [N] int32
+    in [0, num_segments). XLA lowers this reduction to serialised scatters
+    on TPU; here it rides the MXU as a tiled one-hot contraction, the same
+    pattern as the Bloom word gather above."""
     n, p = planes.shape
     b_t = min(_pad_to(n, 8), _BYTE_TILE)
     v_t = min(_pad_to(num_segments, 8), _SEG_TILE)
@@ -242,52 +317,15 @@ def leb128_segment_sum(planes, seg_ids, num_segments: int, *, interpret=False):
         _leb_segsum_kernel,
         grid=(1, v_pad // v_t, n_pad // b_t),
         in_specs=[
-            pl.BlockSpec((b_t, p), lambda g, v, b: (b, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((b_t, 1), lambda g, v, b: (b, 0), memory_space=pltpu.VMEM),
+            pl.BlockSpec((b_t, p), lambda g, v, b: (b, _zero()),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((b_t, 1), lambda g, v, b: (b, _zero()),
+                         memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec(
-            (v_t, p), lambda g, v, b: (v, 0), memory_space=pltpu.VMEM
+            (v_t, p), lambda g, v, b: (v, _zero()), memory_space=pltpu.VMEM
         ),
         out_shape=jax.ShapeDtypeStruct((v_pad, p), jnp.float32),
         interpret=interpret,
     )(planes, seg_ids.reshape(n_pad, 1))
     return out[:num_segments]
-
-
-@profiled_jit("pallas.bloom_build", static_argnames=("num_words", "interpret"))
-def bloom_build(xyz, counts, num_words: int, *, interpret=False):
-    """Pallas analogue of sync_batch.build_filters.
-
-    xyz: [B, E, 3] uint32, counts: [B] int32. Returns (words [B, num_words]
-    uint32, modulo [B] int32) exactly like sync_batch.build_filters."""
-    from .sync_batch import filter_modulo
-
-    batch, e, _ = xyz.shape
-    modulo = filter_modulo(counts)
-    e_t = min(_pad_to(e, 8), _ENTRY_TILE)
-    w_t = min(_pad_to(num_words, _LANES), _WORD_TILE)
-    e_pad = _pad_to(e, e_t)
-    w_pad = _pad_to(num_words, w_t)
-    xyz = jnp.pad(xyz, ((0, 0), (0, e_pad - e), (0, 0)))
-
-    words = pl.pallas_call(
-        _bloom_build_kernel,
-        grid=(batch, w_pad // w_t, e_pad // e_t),
-        in_specs=[
-            pl.BlockSpec(
-                (1, e_t, 3), lambda b, w, ei: (b, ei, 0), memory_space=pltpu.VMEM
-            ),
-            pl.BlockSpec((1, 1), lambda b, w, ei: (b, 0), memory_space=pltpu.SMEM),
-            pl.BlockSpec((1, 1), lambda b, w, ei: (b, 0), memory_space=pltpu.SMEM),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, w_t), lambda b, w, ei: (b, w), memory_space=pltpu.VMEM
-        ),
-        out_shape=jax.ShapeDtypeStruct((batch, w_pad), jnp.int32),
-        interpret=interpret,
-    )(
-        xyz,
-        modulo.reshape(batch, 1).astype(jnp.int32),
-        counts.reshape(batch, 1).astype(jnp.int32),
-    )
-    return words[:, :num_words].astype(jnp.uint32), modulo

@@ -9,7 +9,9 @@ keep it a minority share; this test (and `make bench-smoke`, which runs
 the same check at a larger config via ``bench.py --quick``) fails any
 change that reintroduces O(whole farm) host work per call.
 """
+import json
 import os
+import subprocess
 import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
@@ -89,3 +91,16 @@ def test_decode_cache_absorbs_the_fanout():
     once per doc: decode-cache hits dominate misses."""
     result = _smoke()
     assert result["decode_cache_hits"] > result["decode_cache_misses"], result
+
+
+def test_main_fails_without_an_accelerator():
+    """No CPU fallback: with only the CPU visible, the device benchmark
+    reports an error and exits non-zero instead of printing a rate."""
+    proc = subprocess.run(
+        [sys.executable, bench.__file__],
+        env=dict(os.environ, JAX_PLATFORMS="cpu", AM_LEDGER="0"),
+        capture_output=True, text=True, timeout=300,
+    )
+    assert proc.returncode == 1, proc.stdout + proc.stderr
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert "no accelerator" in out["error"] and "value" not in out

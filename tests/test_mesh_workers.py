@@ -504,3 +504,14 @@ def test_rebalance_policy_hook_is_called_on_interval():
         assert calls == [mesh, mesh]
     finally:
         mesh.close()
+
+
+def test_process_backend_refuses_an_accelerator(monkeypatch):
+    """A chip belongs to one process: until workers are pinned to chips,
+    the process backend runs on the CPU only and says so up front instead
+    of letting N workers race for the chip."""
+    import jax
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(ValueError, match="CPU only"):
+        MeshFarm(8, num_shards=2, mesh_backend="process")

@@ -1,6 +1,9 @@
 """Native C++ codec tests: byte-identity against the pure-Python codecs on
-randomized columns (differential, both directions)."""
+randomized columns (differential, both directions). The library is built
+from source on first load, so these tests always run."""
+import os
 import random
+import shutil
 
 import numpy as np
 import pytest
@@ -15,9 +18,6 @@ from automerge_tpu.codecs import (
     RLEEncoder,
 )
 
-pytestmark = pytest.mark.skipif(
-    not native.available(), reason="native library not built (make -C native)"
-)
 
 
 def random_column(rng, n, null_prob=0.3, value_range=1000):
@@ -110,3 +110,23 @@ class TestNativeCodecs:
         assert [(cid, bytes(buf)) for cid, buf in python_cols] == [
             (cid, bytes(buf)) for cid, buf in native_cols
         ]
+
+
+def test_library_builds_from_source(tmp_path, monkeypatch):
+    """A missing library is built from codecs.cpp through a temp name, and a
+    newer source makes it stale again."""
+    for name in ("codecs.cpp", "Makefile"):
+        shutil.copy(os.path.join(native._NATIVE_DIR, name), tmp_path)
+    monkeypatch.setattr(native, "_NATIVE_DIR", str(tmp_path))
+    monkeypatch.setattr(native, "_LIB_PATH", str(tmp_path / "libamcodecs.so"))
+    monkeypatch.setattr(native, "_SRC_PATH", str(tmp_path / "codecs.cpp"))
+    monkeypatch.setattr(native, "_lib", None)
+    monkeypatch.setattr(native, "_build_tried", False)
+    assert native._stale()
+    assert native.available()
+    assert (tmp_path / "libamcodecs.so").exists()
+    assert not list(tmp_path.glob("*.tmp"))
+    assert not native._stale()
+    later = os.path.getmtime(native._LIB_PATH) + 10
+    os.utime(native._SRC_PATH, (later, later))
+    assert native._stale()
