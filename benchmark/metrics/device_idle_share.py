@@ -1,0 +1,9 @@
+"""100 x (1 - union of device-op intervals / traced window), from the
+profiler trace."""
+
+
+def read(ctx):
+    dev = ctx["device"]
+    if dev is None or not dev["window_s"]:
+        return None
+    return 100.0 * (1.0 - dev["busy_s"] / dev["window_s"])
