@@ -4,7 +4,7 @@
 
 PY ?= python
 
-.PHONY: lint test native obs-report faults bench-smoke gate-bench chaos serve decode mesh mesh-workers mesh-shm prof store sync2
+.PHONY: lint test native obs-report faults bench-smoke gate-bench chaos serve decode mesh mesh-workers mesh-shm store sync2
 
 lint:
 	JAX_PLATFORMS=cpu $(PY) -m automerge_tpu.analysis automerge_tpu
@@ -107,16 +107,6 @@ store:
 # `python bench.py --sync2`
 sync2:
 	JAX_PLATFORMS=cpu $(PY) bench.py --sync2 --quick
-
-# amprof ledger smoke (README "Observability"): run the quick bench with
-# per-program compile/dispatch attribution + memory sampling, append the
-# normalized record to PROF_LEDGER, then render the perf trajectory. Diff
-# the last two comparable runs:
-# `python -m automerge_tpu.obs --ledger ledger.jsonl --diff -2 -1`
-PROF_LEDGER ?= ledger.jsonl
-prof:
-	JAX_PLATFORMS=cpu AM_LEDGER=$(PROF_LEDGER) $(PY) bench.py --quick
-	$(PY) -m automerge_tpu.obs --ledger $(PROF_LEDGER)
 
 native:
 	$(MAKE) -C native

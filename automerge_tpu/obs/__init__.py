@@ -30,13 +30,12 @@ Five parts plus a CLI:
 - **Live telemetry** (`obs.export`): Prometheus-style text exposition
   (mounted on the asyncio adapter's telemetry port), periodic JSONL
   snapshots, and the per-request phase-share math.
-- **amprof** (`obs.prof`, `obs.ledger`): the compiled-program
+- **amprof** (`obs.prof`): the compiled-program
   observatory — every tpu-layer jit program registers a named
   ``ProfiledProgram`` wrapper recording per-program compile/dispatch
   tallies, latency histograms and shape buckets, with a recompile-storm
   detector — plus the memory ``Sampler`` (slab pages, DecodeCache and
-  change-column bytes as ``prof.mem.*`` gauges) and the append-only
-  perf ledger bench runs write their normalized records to.
+  change-column bytes as ``prof.mem.*`` gauges).
 - **SLOs** (`obs.slo`): declared objectives (latency percentile under
   budget, availability, convergence ratio) evaluated as multi-window
   burn rates on an injected clock — simulated and wall clocks both

@@ -15,8 +15,6 @@ and renders it without running anything.
     python -m automerge_tpu.obs --flight dump.jsonl  # flight timeline
     python -m automerge_tpu.obs --watch snaps.jsonl  # live telemetry view
     python -m automerge_tpu.obs --watch snaps.jsonl --follow
-    python -m automerge_tpu.obs --ledger ledger.jsonl           # trajectory
-    python -m automerge_tpu.obs --ledger ledger.jsonl --diff -2 -1
 
 ``--flight`` renders a flight-recorder dump (obs/flight.py) as a
 causally-ordered timeline. ``--watch`` renders the newest line of a
@@ -285,13 +283,6 @@ def main(argv=None) -> int:
                         help="render the newest telemetry snapshot in FILE "
                              "(tenant table + phase shares + flight tail); "
                              "headless one-frame render unless --follow")
-    parser.add_argument("--ledger", metavar="FILE",
-                        help="render the perf-ledger trajectory in FILE "
-                             "(bench-appended JSONL, obs/ledger.py); "
-                             "combine with --diff to compare two records")
-    parser.add_argument("--diff", nargs=2, type=int, metavar=("A", "B"),
-                        help="with --ledger: diff records A and B by index "
-                             "(negative indices count from the end)")
     parser.add_argument("--follow", action="store_true",
                         help="with --watch: keep refreshing top-style")
     parser.add_argument("--interval", type=float, default=1.0,
@@ -301,31 +292,6 @@ def main(argv=None) -> int:
     parser.add_argument("--json", action="store_true",
                         help="print one JSON object instead of tables")
     args = parser.parse_args(argv)
-
-    if args.ledger:
-        from .ledger import (diff_records, load_ledger, render_diff,
-                             render_trajectory)
-
-        records = load_ledger(args.ledger)
-        if args.diff:
-            a_i, b_i = args.diff
-            try:
-                a, b = records[a_i], records[b_i]
-            except IndexError:
-                print(
-                    f"--ledger: diff indices {a_i},{b_i} out of range "
-                    f"({len(records)} record(s))", file=sys.stderr,
-                )
-                return 1
-            if args.json:
-                print(json.dumps(diff_records(a, b), sort_keys=True))
-            else:
-                print(render_diff(a, b))
-        elif args.json:
-            print(json.dumps(records, sort_keys=True))
-        else:
-            print(render_trajectory(records))
-        return 0
 
     if args.flight:
         with open(args.flight, "r", encoding="utf-8") as fh:

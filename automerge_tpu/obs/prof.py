@@ -124,7 +124,9 @@ class ProfiledProgram:
                 reg.histogram(f"prof.program.{name}.compile_ms",
                               "wall time of dispatches that compiled"),
                 reg.histogram(f"prof.program.{name}.dispatch_ms",
-                              "per-dispatch wall time"),
+                              "per-dispatch host wall time: the enqueue, "
+                              "not the device's run (the host's wait for "
+                              "results is amtrace's device_wait)"),
             )
             self._m = m
         return m
@@ -222,6 +224,16 @@ class Observatory:
 
     def programs(self) -> dict:
         return dict(self._programs)
+
+    def modules(self) -> dict:
+        """``{XLA module name: amprof name}``. A jitted function compiles
+        to the HLO module ``jit_<function name>``, the name its device
+        time carries in a profiler trace (``jit_paged_apply_ops`` ->
+        ``paging.apply_ops``)."""
+        return {
+            f"jit_{getattr(prog.fn, '__name__', name)}": name
+            for name, prog in self._programs.items()
+        }
 
     def enable(self) -> None:
         self.enabled = True

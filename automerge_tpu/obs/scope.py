@@ -88,7 +88,9 @@ PHASE_HISTOGRAMS: dict[str, Histogram] = {
     ),
     "device_dispatch": _METRICS.histogram(
         "serve.phase.device_dispatch_ms",
-        "device merge program share of serve dispatches",
+        "host enqueue time of the device merge program in serve "
+        "dispatches (not its device time; the host's wait for results is "
+        "amtrace's device_wait)",
     ),
     "visibility": _METRICS.histogram(
         "serve.phase.readback_ms",

@@ -18,6 +18,17 @@ tasks. This module replaces it with:
 - **Near-zero disabled cost**: `Trace(enabled=False).span(...)` performs a
   single attribute test and never touches the clock or allocates a node
   (asserted by tests/test_obs.py::test_disabled_span_is_attribute_test_only).
+- **Intervals and counters**: `Trace.interval(name)` times a stretch that
+  is not a tree node (the whole `apply_changes` call, a host wait on a
+  device result) into the flat `Trace.counters`, so no span's self time
+  changes. While an enabled trace is installed, a `gc.callbacks` hook
+  counts Python's garbage collections the same way (`gc`, `gc.gen2`).
+- **The profiler's timeline**: with a factory registered by
+  `set_timeline`, an enabled trace also opens an `am.<name>` annotation
+  for every span and interval, and `am.gc.gen<N>` for every collection of
+  generation 1 and up. The device layer registers one that annotates only
+  while a JAX profiler trace records (tpu/jitprof.py), so the marks share
+  the device trace's clock there and cost one check elsewhere.
 
 Histogram buckets are log2-spaced: bucket i covers
 [1µs·2^i, 1µs·2^(i+1)), 28 buckets spanning 1µs to ~134s; out-of-range
@@ -30,8 +41,10 @@ from __future__ import annotations
 
 import contextlib
 import contextvars
+import gc
 import json
 import math
+import threading
 import time
 from typing import Iterator
 
@@ -108,16 +121,19 @@ class SpanNode:
 
 
 class Trace:
-    """A span tree plus the enabled flag. See module docstring."""
+    """A span tree, flat counters and the enabled flag. See module
+    docstring."""
 
-    __slots__ = ("enabled", "root")
+    __slots__ = ("enabled", "root", "counters")
 
     def __init__(self, enabled: bool = True):
         self.enabled = enabled
         self.root = SpanNode("")
+        #: name -> {"seconds": total, "calls": count} (intervals, gc)
+        self.counters: dict[str, dict] = {}
 
     @contextlib.contextmanager
-    def span(self, name: str) -> Iterator[SpanNode | None]:
+    def span(self, name: str, **args) -> Iterator[SpanNode | None]:
         if not self.enabled:
             yield None
             return
@@ -125,18 +141,48 @@ class Trace:
         parent = state[1] if state[0] is self else self.root
         node = parent.child(name)
         token = _STATE.set((self, node))
+        mark = _mark(name, args)
         start = time.perf_counter()
         try:
             yield node
         finally:
             node.record(time.perf_counter() - start)
+            if mark is not None:
+                mark.__exit__(None, None, None)
             _STATE.reset(token)
 
     # the historical PhaseProfile spelling; same ambient/nesting semantics
     phase = span
 
+    @contextlib.contextmanager
+    def interval(self, name: str, **args) -> Iterator[None]:
+        """Times the extent into ``counters[name]`` and onto the timeline
+        as ``am.<name>``, with no span node: the enclosing span's self
+        time and the tree's shape stay as they were."""
+        if not self.enabled:
+            yield
+            return
+        mark = _mark(name, args)
+        start = time.perf_counter()
+        try:
+            yield
+        finally:
+            self.count(name, time.perf_counter() - start)
+            if mark is not None:
+                mark.__exit__(None, None, None)
+
+    def count(self, name: str, seconds: float, calls: int = 1) -> None:
+        """Adds `seconds` and `calls` to the flat counter `name`."""
+        entry = self.counters.get(name)
+        if entry is None:
+            self.counters[name] = {"seconds": seconds, "calls": calls}
+        else:
+            entry["seconds"] += seconds
+            entry["calls"] += calls
+
     def reset(self) -> None:
         self.root = SpanNode("")
+        self.counters = {}
 
     # ------------------------------------------------------------------ #
     # aggregation (PhaseProfile compatibility surface)
@@ -211,7 +257,8 @@ class Trace:
     # JSON-lines export / import
 
     def to_jsonl(self) -> str:
-        """One JSON object per span node, carrying its path from the root —
+        """One JSON object per span node, carrying its path from the root,
+        then one per counter (``{"counter": name, "seconds", "calls"}``) —
         a flat, stream-appendable trace dump."""
         lines: list[str] = []
 
@@ -227,6 +274,9 @@ class Trace:
 
         for top in self.root.children.values():
             walk(top, [top.name])
+        for name, entry in self.counters.items():
+            lines.append(json.dumps({"counter": name, **entry},
+                                    sort_keys=True))
         return "\n".join(lines) + ("\n" if lines else "")
 
     @classmethod
@@ -245,12 +295,16 @@ class Trace:
         ships ``to_jsonl()`` back with the result frame, and the
         controller absorbs it — so ``--watch`` still attributes
         device_dispatch/transcode time per shard even when the shard
-        lives in another process."""
+        lives in another process. Counters add up the same way."""
         for line in text.splitlines():
             line = line.strip()
             if not line:
                 continue
             entry = json.loads(line)
+            if "counter" in entry:
+                self.count(entry["counter"], entry["seconds"],
+                           entry["calls"])
+                continue
             node = self.root
             for name in entry["path"]:
                 node = node.child(name)
@@ -282,17 +336,95 @@ _STATE: contextvars.ContextVar[tuple[Trace, SpanNode]] = contextvars.ContextVar(
 )
 
 
+#: the timeline factory (`set_timeline`)
+_TIMELINE: list = [None]
+
+
+def _mark(name: str, args: dict):
+    """The entered ``am.<name>`` timeline annotation, or None."""
+    timeline = _TIMELINE[0]
+    if timeline is None:
+        return None
+    mark = timeline(f"am.{name}", **args)
+    if mark is not None:
+        mark.__enter__()
+    return mark
+
+
 def get_trace() -> Trace:
     """The ambient trace (a disabled no-op unless one is installed)."""
     return _STATE.get()[0]
 
 
+def set_timeline(factory):
+    """Sets the timeline factory of every enabled trace and returns the
+    previous one (None: no timeline). It is called as
+    ``factory("am.<name>", **args)`` and returns a context manager, or None
+    when nothing records (tpu/jitprof.py's ``profiler_mark``)."""
+    previous, _TIMELINE[0] = _TIMELINE[0], factory
+    return previous
+
+
 @contextlib.contextmanager
 def use_trace(trace: Trace) -> Iterator[Trace]:
     """Installs `trace` as the ambient trace for the dynamic extent, in the
-    current context only."""
+    current context only. While any enabled trace is installed, Python's
+    garbage collections are counted on the ambient trace (`_on_gc`)."""
     token = _STATE.set((trace, trace.root))
+    hooked = trace.enabled
+    if hooked:
+        _hook_gc(1)
     try:
         yield trace
     finally:
         _STATE.reset(token)
+        if hooked:
+            _hook_gc(-1)
+
+
+# ---------------------------------------------------------------------- #
+# garbage collection: a pause the host takes inside whatever span is open
+
+class _GcHook:
+    """The ``gc.callbacks`` entry's state: how many enabled traces are
+    installed, and the running collection's start and timeline mark."""
+
+    users = 0
+    lock = threading.Lock()
+    start = 0.0
+    mark = None
+
+
+def _hook_gc(delta: int) -> None:
+    """Reference-counts the installed enabled traces (across threads); the
+    callback is in ``gc.callbacks`` only while there is one, so an
+    untraced process pays nothing per collection."""
+    with _GcHook.lock:
+        _GcHook.users += delta
+        if delta > 0 and _GcHook.users == 1:
+            gc.callbacks.append(_on_gc)
+        elif delta < 0 and _GcHook.users == 0:
+            gc.callbacks.remove(_on_gc)
+
+
+def _on_gc(phase: str, info: dict) -> None:
+    """Counts a collection on the ambient trace: ``gc`` (every
+    generation) and ``gc.gen2`` (full collections), seconds and calls.
+    Collections of generation 1 and up are marked on the timeline as
+    ``am.gc.gen<N>``; the frequent, short generation-0 ones only count."""
+    trace = _STATE.get()[0]
+    if not trace.enabled:
+        return
+    if phase == "start":
+        generation = info["generation"]
+        if generation:
+            _GcHook.mark = _mark(f"gc.gen{generation}", {})
+        _GcHook.start = time.perf_counter()
+        return
+    seconds = time.perf_counter() - _GcHook.start
+    mark, _GcHook.mark = _GcHook.mark, None
+    if mark is not None:
+        mark.__exit__(None, None, None)
+    trace.count("gc", seconds)
+    if info["generation"] == 2:
+        trace.count("gc.gen2", seconds)

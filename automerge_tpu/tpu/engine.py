@@ -34,6 +34,7 @@ import numpy as np
 from ..obs.flight import get_flight
 from ..obs.metrics import get_metrics
 from ..obs.prof import get_observatory
+from ..obs.spans import get_trace
 from ..testing.faults import fire as _fault_point
 from .jitprof import profiled_jit
 
@@ -583,7 +584,9 @@ class BatchedMapEngine:
         numpy arrays concatenated in plan order. Visibility is computed
         for ONLY the planned docs' rows, then one padded device gather and
         ONE jax.device_get move exactly the requested rows — O(rows
-        requested), not O(whole farm state)."""
+        requested), not O(whole farm state). The host's wait for that
+        result (the device's queue and run included) is the ambient
+        trace's ``device_wait`` interval."""
         plan = [
             (int(d), np.asarray(idx, np.int64))
             for d, idx in plan if len(idx)
@@ -602,7 +605,8 @@ class BatchedMapEngine:
         idx = np.zeros(padded, np.int64)
         idx[:n] = flat
         v, t = _dispatch(_gather_rows, visible, totals, jnp.asarray(idx))
-        v, t = jax.device_get((v, t))
+        with get_trace().interval("device_wait"):
+            v, t = jax.device_get((v, t))
         return v[:n], t[:n]
 
     def read_patch_columns(self, plan, actor_rank):
@@ -643,7 +647,8 @@ class BatchedMapEngine:
             patch_column_rows, visible, totals, op,
             jnp.asarray(actor_rank), jnp.asarray(idx), jnp.asarray(cut),
         )
-        v, t, e = jax.device_get((v, t, e))
+        with get_trace().interval("device_wait"):
+            v, t, e = jax.device_get((v, t, e))
         return v[:n], t[:n], e[:n]
 
     def dense_view(self, docs=None):

@@ -228,8 +228,9 @@ _M_TC_ORACLE = _METRICS.counter(
 # latency spike links back to the batched request traces it served.
 _M_DISPATCH_MS = _METRICS.histogram(
     "farm.dispatch.latency_ms",
-    "host-measured batched device merge dispatch latency; exemplars name "
-    "the owning serve dispatch span",
+    "host enqueue time of the batched device merge (not its device time; "
+    "the host's wait for results is amtrace's device_wait); exemplars "
+    "name the owning serve dispatch span",
 )
 _M_READBACK_MS = _METRICS.histogram(
     "farm.readback.latency_ms",
@@ -511,6 +512,7 @@ class TpuDocFarm:
         # to the sequential walk after a device-path failure
         self.quarantine_threshold = quarantine_threshold
         self.fault_counts = [0] * num_docs
+        self.apply_calls = 0  # numbers apply_changes on the timeline
         self.quarantine: dict[int, BaseException] = {}
         self.degraded: set[int] = set()
         # host mirror of the device op table (incremental readback, README
@@ -1279,37 +1281,75 @@ class TpuDocFarm:
           batch before anything commits).
 
         Phases (recorded on the ambient PhaseProfile, SURVEY §5.1):
-        decode -> walk (exact docs) -> gate+transcode -> pack ->
-        device_dispatch -> visibility (host mirror merge + scoped
-        device readback of stale spans) -> patch_assembly (vectorized
-        over the mirror)."""
+        prepare -> decode -> prevalidate -> walk (exact docs) ->
+        gate+transcode ->
+        pack -> device_dispatch (the host's enqueue of the merge, not
+        its run on the device) -> visibility (host mirror merge + scoped
+        device readback of stale spans; the host's wait for the device is
+        the ``device_wait`` interval) -> patch_assembly (vectorized over
+        the mirror). The whole call is the ``apply_changes`` interval
+        (a counter and a timeline mark, not a span), numbered per farm."""
         from ..profiling import get_profile
 
+        prof = get_profile()
+        self.apply_calls += 1
+        if not prof.enabled:
+            return self._apply_changes(prof, per_doc_buffers, is_local,
+                                       isolation)
+        with prof.interval(
+            "apply_changes", call=self.apply_calls,
+            docs=sum(1 for b in per_doc_buffers if b),
+            changes=sum(len(b) for b in per_doc_buffers),
+        ):
+            return self._apply_changes(prof, per_doc_buffers, is_local,
+                                       isolation)
+
+    def _apply_changes(self, prof, per_doc_buffers, is_local, isolation):
         if isolation not in ("doc", "batch"):
             raise ValueError(f"unknown isolation mode: {isolation!r}")  # amlint: disable=AM401 — API-usage validation
         doc_mode = isolation == "doc"
 
-        prof = get_profile()
-        assert len(per_doc_buffers) == self.num_docs
-        per_doc_rows = [[] for _ in range(self.num_docs)]
-        per_doc_arrays = [None] * self.num_docs
-        applied_ops = [[] for _ in range(self.num_docs)]
-        touched_objects = [set() for _ in range(self.num_docs)]
-        applied_changes = [[] for _ in range(self.num_docs)]
-        exact_patches: dict[int, dict] = {}
-        # fault-domain state for this call (isolation="doc")
-        failures: dict[int, BaseException] = {}
-        snapshots: dict[int, dict] = {}
-        fallback_docs: set[int] = set()
-        attempted = [d for d in range(self.num_docs) if per_doc_buffers[d]]
-        # WAL capture: remember each attempted doc's committed-change count.
-        # The delta at return is exactly what this call committed — uniform
-        # across the columnar gate, the scalar oracle and the fallback walk,
-        # and naturally zero for docs a quarantine rollback restored.
-        store_marks = (
-            {d: len(self.changes[d]) for d in attempted}
-            if self.store is not None else None
-        )
+        # the call's per-document working lists (several per document)
+        # and the quarantine shedding, under a phase of their own so the
+        # collections they trigger are not left between phases
+        with prof.phase("prepare"):
+            assert len(per_doc_buffers) == self.num_docs
+            per_doc_rows = [[] for _ in range(self.num_docs)]
+            per_doc_arrays = [None] * self.num_docs
+            applied_ops = [[] for _ in range(self.num_docs)]
+            touched_objects = [set() for _ in range(self.num_docs)]
+            applied_changes = [[] for _ in range(self.num_docs)]
+            exact_patches: dict[int, dict] = {}
+            # fault-domain state for this call (isolation="doc")
+            failures: dict[int, BaseException] = {}
+            snapshots: dict[int, dict] = {}
+            fallback_docs: set[int] = set()
+            attempted = [
+                d for d in range(self.num_docs) if per_doc_buffers[d]
+            ]
+            # WAL capture: remember each attempted doc's committed-change
+            # count. The delta at return is exactly what this call
+            # committed — uniform across the columnar gate, the scalar
+            # oracle and the fallback walk, and naturally zero for docs a
+            # quarantine rollback restored.
+            store_marks = (
+                {d: len(self.changes[d]) for d in attempted}
+                if self.store is not None else None
+            )
+
+            # quarantined docs shed their traffic before any work happens
+            if doc_mode and self.quarantine:
+                per_doc_buffers = list(per_doc_buffers)
+                for d, cause in self.quarantine.items():
+                    if per_doc_buffers[d]:
+                        per_doc_buffers[d] = []
+                        failures[d] = QuarantinedError(
+                            f"document {d} is quarantined after "
+                            f"{self.fault_counts[d]} failed deliveries (last "
+                            f"cause: {cause}); release_quarantine({d}) to "
+                            "restore traffic"
+                        )
+                        _M_Q_SHED.inc()
 
         def quarantine(d, exc):
             """Captures one doc's failure: rolls its state back, drops its
@@ -1356,20 +1396,6 @@ class TpuDocFarm:
                     )
                     _FLIGHT.trigger("farm.quarantine", doc=d)
 
-        # quarantined docs shed their traffic before any work happens
-        if doc_mode and self.quarantine:
-            per_doc_buffers = list(per_doc_buffers)
-            for d, cause in self.quarantine.items():
-                if per_doc_buffers[d]:
-                    per_doc_buffers[d] = []
-                    failures[d] = QuarantinedError(
-                        f"document {d} is quarantined after "
-                        f"{self.fault_counts[d]} failed deliveries (last "
-                        f"cause: {cause}); release_quarantine({d}) to "
-                        "restore traffic"
-                    )
-                    _M_Q_SHED.inc()
-
         with prof.phase("decode"):
             # batched first-touch decode: every distinct cache miss in the
             # delivery parses in ONE vector pass (tpu/decode) — the per-doc
@@ -1406,16 +1432,17 @@ class TpuDocFarm:
         # doc commits, so re-scanning the queue would be O(queue ops) of
         # redundant work per call (ADVICE round 5). Docs that do receive
         # changes still re-scan their queue inside _prevalidate_limits.
-        for d, decoded in enumerate(per_doc_decoded):
-            if not decoded:
-                continue
-            try:
-                self._prevalidate_limits(d, decoded)
-            except ValueError as exc:
-                if not doc_mode:
-                    _M_ABORTS.inc()
-                    raise
-                quarantine(d, exc)
+        with prof.phase("prevalidate"):
+            for d, decoded in enumerate(per_doc_decoded):
+                if not decoded:
+                    continue
+                try:
+                    self._prevalidate_limits(d, decoded)
+                except ValueError as exc:
+                    if not doc_mode:
+                        _M_ABORTS.inc()
+                        raise
+                    quarantine(d, exc)
 
         # list/text-targeting docs route through the reference walk, whose
         # patch is authoritative for them (byte-exact edit streams; see

@@ -14,12 +14,30 @@ was on a bare ``@partial(jax.jit, ...)``)::
                   donate_argnums=(0,))
     def paged_apply_ops(slab, ...):
         ...
+
+It also registers ``profiler_mark`` as amtrace's timeline factory
+(obs/spans.py): while whoever runs ``jax.profiler`` has a trace
+recording, every span, interval and collection of an enabled amtrace
+trace becomes an ``am.<name>`` annotation on the device lines' clock.
+With no profiler trace recording, nothing is annotated.
 """
 from __future__ import annotations
 
 import jax
 
 from ..obs.prof import ProfiledProgram, get_observatory
+from ..obs.spans import set_timeline
+
+
+def profiler_mark(name: str, **args):
+    """A ``jax.profiler.TraceAnnotation`` while a profiler trace records,
+    else None (one check, nothing allocated)."""
+    if jax.profiler.TraceAnnotation.is_enabled():
+        return jax.profiler.TraceAnnotation(name, **args)
+    return None
+
+
+set_timeline(profiler_mark)
 
 
 def profiled_jit(name: str, **jit_kwargs):

@@ -24,18 +24,6 @@ CHILD_TIMEOUT = int(os.environ.get("BENCH_CHILD_TIMEOUT", "420"))
 PROBE_TIMEOUT = int(os.environ.get("BENCH_PROBE_TIMEOUT", "120"))
 
 
-def _ledger_append(record):
-    """Appends a normalized perf record to the ledger (obs/ledger.py).
-    AM_LEDGER overrides the path; AM_LEDGER=0 (or empty) disables the
-    append entirely — the gates never depend on the ledger existing."""
-    path = os.environ.get("AM_LEDGER", os.path.join(_REPO, "ledger.jsonl"))
-    if not path or path == "0":
-        return
-    from automerge_tpu.obs.ledger import append_record
-
-    append_record(path, record)
-
-
 def bench_device(num_docs, capacity, rounds, ops_per_round, seed=0):
     import jax
     import jax.numpy as jnp
@@ -487,8 +475,7 @@ def _quick_main():
     times during the steady-state delta rounds (the amprof observatory's
     per-program attribution — a shape-bucket regression shows up as one
     named program blowing its budget, not as an anonymous recompile
-    counter). The run appends its normalized record to the perf ledger
-    (see _ledger_append / `python -m automerge_tpu.obs --ledger`)."""
+    counter)."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")  # host gate: no TPU needed
     num_docs = int(os.environ.get("BENCH_SMOKE_DOCS", "128"))
     threshold = float(os.environ.get("BENCH_SMOKE_MAX_TAIL_SHARE", "0.55"))
@@ -508,15 +495,6 @@ def _quick_main():
         and not over_budget
         and bool(result["programs"])  # attribution must actually populate
     )
-    _ledger_append({
-        "kind": "quick",
-        "config": {"docs": num_docs, "bench": "smoke"},
-        "ops_per_sec": round(result["ops_per_sec"]),
-        "phases": result["phases"],
-        "programs": result["programs"],
-        "mem": result["mem"],
-        "ok": ok,
-    })
     print(json.dumps({
         "metric": "visibility+patch_assembly share of delta-round time",
         "value": result["tail_share"],
@@ -1198,22 +1176,6 @@ def _mesh_child_main():
             and result["scaling"]["device_dispatch"] >= dd_floor
         )
     result["ok"] = ok
-    _ledger_append({
-        "kind": (f"mesh-{backend}"
-                 + (f"-{result['mesh_transport']}"
-                    if backend == "process" else "")
-                 + ("-quick" if quick else "")),
-        "config": {"docs": num_docs, "rounds": rounds, "ops": ops,
-                   "backend": backend,
-                   "transport": result["mesh_transport"],
-                   "shards": result["num_shards"]},
-        "ops_per_sec": result["aggregate_ops_per_sec"],
-        "phases": result["phases_s"],
-        "programs": result["programs"],
-        "pipe": result["pipe"],
-        "shm": result["shm"],
-        "ok": ok,
-    })
     print("BENCH_RESULT " + json.dumps(result))
 
 
@@ -1777,8 +1739,7 @@ def _sync2_main(quick):
     - every farm generate sweep resolves ALL v2 channels' fingerprints as
       ONE observatory-pinned device dispatch.
 
-    The full run writes SYNC_r01.json + a perf-ledger row (visible via
-    `python -m automerge_tpu.obs --ledger ledger.jsonl --diff -2 -1`)."""
+    The full run writes SYNC_r01.json."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     n = int(os.environ.get("BENCH_SYNC2_N", "4000" if quick else "100000"))
     loss = float(os.environ.get("BENCH_SYNC2_LOSS", "0.3"))
@@ -1811,20 +1772,6 @@ def _sync2_main(quick):
     }
     print(json.dumps(out))
     if not quick:
-        _ledger_append({
-            "kind": "sync2",
-            "config": {"changes": n, "loss": loss,
-                       "soak_changes": soak_changes, "soak_ops": soak_ops},
-            "ops_per_sec": soak_v2["ops_per_sec"],
-            "phases": {"reconcile": reconcile["elapsed_s"],
-                       "soak_v1": soak_v1["elapsed_s"],
-                       "soak_v2": soak_v2["elapsed_s"]},
-            "round_trips": reconcile["round_trips"],
-            "bound": reconcile["bound"],
-            "v1_watchdog_events": soak_v1["watchdog_events"],
-            "v2_watchdog_events": soak_v2["watchdog_events"],
-            "ok": ok,
-        })
         with open(os.path.join(_REPO, "SYNC_r01.json"), "w") as f:
             json.dump(out, f, indent=2)
             f.write("\n")
@@ -1982,7 +1929,7 @@ def _store_main(quick):
     writer's change log byte-for-byte, recovery is clean, and every
     committed change is accounted for. The full run additionally gates
     batched hydration >= BENCH_STORE_HYDRATE_FLOOR x the per-doc load
-    loop and writes STORE_r01.json + a perf-ledger row."""
+    loop and writes STORE_r01.json."""
     os.environ.setdefault("JAX_PLATFORMS", "cpu")
     if quick:
         num_docs = int(os.environ.get("BENCH_STORE_DOCS", "24"))
@@ -2011,17 +1958,6 @@ def _store_main(quick):
     }
     print(json.dumps(out))
     if not quick:
-        _ledger_append({
-            "kind": "store",
-            "config": {"docs": num_docs, "rounds": rounds, "ops": ops},
-            "ops_per_sec": result["cold_start"]["docs_per_sec"],
-            "phases": {"cold_start_batched": result["cold_start"]["batched_s"],
-                       "cold_start_sequential":
-                           result["cold_start"]["sequential_s"],
-                       "wal": result["wal"]["wal_s"],
-                       "bare": result["wal"]["bare_s"]},
-            "ok": ok,
-        })
         with open(os.path.join(_REPO, "STORE_r01.json"), "w") as f:
             json.dump(out, f, indent=2)
             f.write("\n")

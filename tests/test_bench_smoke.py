@@ -98,7 +98,7 @@ def test_main_fails_without_an_accelerator():
     reports an error and exits non-zero instead of printing a rate."""
     proc = subprocess.run(
         [sys.executable, bench.__file__],
-        env=dict(os.environ, JAX_PLATFORMS="cpu", AM_LEDGER="0"),
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
         capture_output=True, text=True, timeout=300,
     )
     assert proc.returncode == 1, proc.stdout + proc.stderr

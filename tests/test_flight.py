@@ -320,7 +320,10 @@ def test_session_retry_exhaustion_records_and_dumps(tmp_path):
 def test_engine_recompile_event_names_shape_bucket():
     with enabled_flight() as rec:
         rec.clear()
-        farm = TpuDocFarm(2, capacity=32)
+        # a slab (10 pages) no other test builds: the event needs a fresh
+        # compile, and test files that ran earlier in the same process
+        # leave their shapes in the jit cache
+        farm = TpuDocFarm(2, capacity=320)
         from automerge_tpu.obs.metrics import enabled_metrics
 
         with enabled_metrics():
