@@ -18,6 +18,12 @@ op tensors (automerge_tpu/tpu). Deletion is not a row: a 'del' op only appends
 its opId to the succ lists of the ops it overwrites (new.js:1204-1217); an op
 is visible iff it has no successors.
 
+Committed rows are immutable tuples whose succ columns are tuples too: a
+successor insert builds a new row. CPython's collector untracks a tuple of
+ints, strings, bytes, None and untracked tuples when a collection sees it (a
+row with successors at the collection after its succ tuples'), so the
+document's op rows, most of a sync server's heap, stay out of full passes.
+
 Patch generation reproduces the reference's incremental patch state machine
 (updatePatchProperty, appendEdit/appendUpdate/convertInsertToUpdate,
 new.js:747-1040) exactly, so patches are bit-identical JSON.
@@ -49,7 +55,10 @@ from .codecs import Encoder
 from .common import parse_op_id, utf16_key
 from .errors import CausalityError, DecodeError
 
-# Row field indices, matching the doc/change column layout (new.js:10-12)
+# Row field indices, matching the doc/change column layout (new.js:10-12).
+# Committed doc rows are immutable 16-tuples with tuple succ columns (empty:
+# the shared ()), so the collector untracks them; only the change op being
+# merged (`_ChangeState.next_op`) is a list.
 OBJ_ACTOR, OBJ_CTR, KEY_ACTOR, KEY_CTR, KEY_STR = 0, 1, 2, 3, 4
 ID_ACTOR, ID_CTR, INSERT, ACTION, VAL_LEN, VAL_RAW = 5, 6, 7, 8, 9, 10
 CHLD_ACTOR, CHLD_CTR = 11, 12
@@ -212,8 +221,8 @@ class _ChangeState:
 
 
 def _read_op_rows(columns, column_spec, actor_table=None):
-    """Decodes column buffers into flat op rows (lists). ACTOR_ID values are
-    translated through actor_table when given; group columns become lists.
+    """Decodes column buffers into flat op rows (tuples). ACTOR_ID values are
+    translated through actor_table when given; group columns become tuples.
 
     Port of readOperation (new.js:570) applied across the whole column set.
     """
@@ -247,14 +256,14 @@ def _read_op_rows(columns, column_spec, actor_table=None):
         row[CHLD_CTR] = ds[CHLD_CTR].read_value()
         card = ds[13].read_value() or 0
         row[13] = card
-        row[14] = [ds[14].read_value() for _ in range(card)]
-        row[15] = [ds[15].read_value() for _ in range(card)]
+        row[14] = tuple([ds[14].read_value() for _ in range(card)])
+        row[15] = tuple([ds[15].read_value() for _ in range(card)])
         if actor_table is not None:
             for idx in (OBJ_ACTOR, KEY_ACTOR, ID_ACTOR, CHLD_ACTOR):
                 if row[idx] is not None:
                     row[idx] = actor_table[row[idx]]
-            row[14] = [actor_table[a] if a is not None else None for a in row[14]]
-        rows.append(row)
+            row[14] = tuple([actor_table[a] if a is not None else None for a in row[14]])
+        rows.append(tuple(row))
     return rows
 
 
@@ -827,21 +836,24 @@ def _merge_doc_change_ops(patches, out_rows, change_state, doc_state, list_index
             for op_index, op in enumerate(change_ops):
                 for i in range(op[PRED_NUM]):
                     if op[PRED_ACTOR][i] == doc_op[ID_ACTOR] and op[PRED_CTR][i] == doc_op[ID_CTR]:
-                        # Copy-on-write so rows shared with the committed
-                        # state are never mutated in place
-                        doc_op = list(doc_op)
-                        doc_op[SUCC_ACTOR] = list(doc_op[SUCC_ACTOR])
-                        doc_op[SUCC_CTR] = list(doc_op[SUCC_CTR])
+                        # Rows are immutable tuples (shared with the
+                        # committed state): build the succ columns, then
+                        # one new row
+                        succ_num = doc_op[SUCC_NUM]
+                        succ_actors = list(doc_op[SUCC_ACTOR])
+                        succ_ctrs = list(doc_op[SUCC_CTR])
                         j = 0
-                        while j < doc_op[SUCC_NUM] and (
-                            doc_op[SUCC_CTR][j] < op[ID_CTR]
-                            or (doc_op[SUCC_CTR][j] == op[ID_CTR]
-                                and actor_ids[doc_op[SUCC_ACTOR][j]] < id_actor)
+                        while j < succ_num and (
+                            succ_ctrs[j] < op[ID_CTR]
+                            or (succ_ctrs[j] == op[ID_CTR]
+                                and actor_ids[succ_actors[j]] < id_actor)
                         ):
                             j += 1
-                        doc_op[SUCC_CTR].insert(j, op[ID_CTR])
-                        doc_op[SUCC_ACTOR].insert(j, id_actor_index)
-                        doc_op[SUCC_NUM] += 1
+                        succ_ctrs.insert(j, op[ID_CTR])
+                        succ_actors.insert(j, id_actor_index)
+                        doc_op = doc_op[:SUCC_NUM] + (
+                            succ_num + 1, tuple(succ_actors), tuple(succ_ctrs)
+                        )
                         pred_seen[op_index][i] = True
                         break
 
@@ -892,7 +904,7 @@ def _merge_doc_change_ops(patches, out_rows, change_state, doc_state, list_index
                             "no matching operation for pred: "
                             f"{op[PRED_CTR][j]}@{actor_ids[op[PRED_ACTOR][j]]}"
                         )
-                new_row = op[:13] + [0, [], []]
+                new_row = tuple(op[:SUCC_NUM]) + (0, (), ())
                 out_rows.append(new_row)
                 _update_patch_property(
                     patches, object_id, new_row, doc_state, prop_state, list_index, None, False
