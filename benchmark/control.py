@@ -8,10 +8,10 @@ Runs the cell as run.py does, with the timed path broken underneath
 The control (`lose_ack`: one acknowledged change per delivery never
 reaches the farm) breaks the configuration's stated guarantee and has to
 come out not correct; benchmark/tests/test_faults.py keeps the same
-faults as tests at a size a CPU test run can hold. With ``--fault none
---unlisted`` it runs a cell left out of BENCHMARK.json soundly, as the
-witness of a program fault (PERF.md, Open questions). The benchmark's own
-runs never run this. Runs on the chip only.
+faults as tests at a size a CPU test run can hold. With ``--unlisted``
+it runs a cell that is kept as files but left out of BENCHMARK.json
+(``--fault none`` for a sound run). The benchmark's own runs never run
+this. Runs on the chip only.
 """
 import time
 
@@ -34,7 +34,7 @@ def main(argv=None) -> int:
                         help="a harness.FAULTS name, or none for a sound run")
     parser.add_argument("--unlisted", action="store_true",
                         help="run a cell left out of BENCHMARK.json from "
-                        "its cell file (the fault witness)")
+                        "its cell file")
     args = parser.parse_args(argv)
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     sys.path.insert(0, ROOT)

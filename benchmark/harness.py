@@ -50,7 +50,7 @@ def load_cell(name: str, root: str = REPO, unlisted: bool = False) -> dict:
     """The cell's manifest entry, cell file, configuration, traffic mix,
     and the end-to-end and per-layer metrics it reports. With `unlisted`,
     a cell left out of BENCHMARK.json runs from the ``entry`` its cell
-    file keeps (the fault witness and its test; run.py never does)."""
+    file keeps (control.py can run it; run.py never does)."""
     manifest = _load_json(root, "BENCHMARK.json")
     bench = os.path.join(root, manifest["paths"][0])
     cells = {w["name"]: w for w in manifest["workloads"]}
@@ -676,11 +676,13 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool,
         dev_red = tracereduce.reduce_dir(trace_path, len(devices))
         ctx = {
             "spans": spans_self_s(prof),
+            "counters": prof.counters,
             "kop": window_ops / 1000.0,
             "device": dev_red,
             "compiles_in_window": window_compiles,
             "compiled_in_window": grown,
         }
+        say_trace(dev_red, prof.counters, obs.modules())
         units = {m["name"]: m["unit"] for m in cfg["per_layer"]}
         for m in cfg["per_layer"]:
             value = load_reader(bench, m["name"])(ctx)
@@ -753,3 +755,39 @@ def spans_self_s(prof) -> dict:
         out[node.name] = out.get(node.name, 0.0) + node.total_s - child
         stack.extend(node.children.values())
     return out
+
+
+def say_trace(dev: dict | None, counters: dict, modules: dict) -> None:
+    """Prints what the traced run's reduction holds beyond the metrics:
+    the device-idle split by ``am.*`` span, the labelled gaps, the marks,
+    the residual of the calls, the program's counters, and device seconds
+    by amprof program (`modules`: XLA module name -> amprof name)."""
+    say("program counters in the window (s, calls): " + ", ".join(
+        f"{k} {c['seconds']:.4f} x{c['calls']}"
+        for k, c in sorted(counters.items())))
+    if dev is None:
+        return
+    by_name: dict = {}
+    for module, seconds in dev["programs"].items():
+        name = modules.get(f"jit_{module}", f"jit_{module}")
+        by_name[name] = by_name.get(name, 0.0) + seconds
+    say("device seconds by amprof program: " + ", ".join(
+        f"{k} {v:.4f}" for k, v in sorted(by_name.items(),
+                                           key=lambda kv: -kv[1])))
+    out = dev["timeline"]
+    if out is None:
+        return
+    ms = {k: round(v * 1000.0, 3) for k, v in out["idle_by_span"].items()}
+    say(f"device idle by span (ms): idle={out['idle_s'] * 1000.0:.3f} "
+        f"in_apply={out['idle_in_apply_s'] * 1000.0:.3f} {ms}")
+    say("idle gaps by span (s): " + ", ".join(
+        f"{label} {s:.4f}" for label, s in out["idle_gaps"]))
+    say("am marks in the window (s, calls): " + ", ".join(
+        f"{k} {m['seconds']:.4f} x{m['calls']}"
+        for k, m in sorted(out["marks"].items())))
+    apply_s = out["apply_s"] or 1.0
+    say(f"apply_changes outside its phases: {out['residual_s']:.4f} s "
+        f"of {out['apply_s']:.4f} s "
+        f"({100.0 * out['residual_s'] / apply_s:.3f}%); under no mark: "
+        f"{out['unnamed_s']:.4f} s "
+        f"({100.0 * out['unnamed_s'] / apply_s:.3f}%)")
