@@ -15,6 +15,12 @@ both JSON files read here. Everything is made from ``--seed``:
   previous change and the document's latest change due ``sync_lag_ms``
   earlier.
 
+A configuration's preload is either a few shared template histories
+(``map_templates``, ``text_templates``: documents share them, ``d %
+templates``) or, with ``"preload": {"kind": "trace", ...}``, an editing
+history of each document's own (``trace_history``), made in the worker that
+generates the document's traffic.
+
 Each generated change carries its wire bytes (encoded with the program's
 ``encode_change``, the wire format's encoder) and the same ops as plain
 tuples, which is all the reference (``reference.py``) reads.
@@ -272,6 +278,144 @@ def text_templates(config: dict):
     return out
 
 
+def _run_lengths(total: int, mean: float, rng) -> list:
+    """Geometric run lengths (at least 1, mean `mean`) summing to `total`
+    exactly: the last run is cut to fit."""
+    p = 1.0 / mean
+    out, left = [], total
+    while left:
+        n = 1
+        while rng.random() > p:
+            n += 1
+        n = min(n, left)
+        out.append(n)
+        left -= n
+    return out
+
+
+def trace_history(config: dict, seed: int, doc: int):
+    """Document `doc`'s own editing history, shaped after automerge-perf's
+    editing trace: one author types runs of characters at a cursor that
+    jumps now and then, and deletes runs of characters with backspace.
+
+    The counts are exact: ``keystrokes`` keystrokes, of which ``deletions``
+    delete a visible character, leave ``final_chars`` (``keystrokes - 2 *
+    deletions``) visible of ``keystrokes - deletions`` elements. Typing and
+    backspace runs are geometric (``typing_run_mean``,
+    ``backspace_run_mean``); each backspace run follows a typing run drawn
+    at random, and is put off while the text is shorter than it; before
+    each run the cursor jumps to a random position with probability
+    ``jump_p`` (a backspace run too near the start jumps as well). The
+    history is cut into changes of ``keystrokes_per_change`` keystrokes,
+    the first of which also makes the text object. The author's actor id
+    is drawn from (seed, doc), so no two documents share an element id.
+
+    Returns (buffers, ref ops per change, state) as a template does; the
+    state holds every element in RGA order and the positions of those the
+    history deleted."""
+    from automerge_tpu.columnar import encode_change
+
+    pre = config["preload"]
+    keys, dels = pre["keystrokes"], pre["deletions"]
+    if keys - 2 * dels != pre["final_chars"]:
+        raise ValueError("trace preload: keystrokes - 2 * deletions must be "
+                         "final_chars")
+    per_change, jump_p = pre["keystrokes_per_change"], pre["jump_p"]
+    rng = random.Random(f"{seed}:{doc}:trace")
+    actor = actor_id(seed, doc, "trace")
+    text = (1, actor)
+    text_id = opid(*text)
+    typing = _run_lengths(keys - dels, pre["typing_run_mean"], rng)
+    after: dict[int, list] = {}  # typing run -> backspace runs after it
+    for n in _run_lengths(dels, pre["backspace_run_mean"], rng):
+        after.setdefault(rng.randrange(len(typing)), []).append(n)
+
+    # element k + 1 is the k-th insert; 0 stands for the head. A single
+    # author's new element has the greatest opId, so RGA puts it right
+    # after its reference: `nxt` is the whole sequence as a linked list.
+    e_ctr = [0]
+    nxt = [-1]
+    dead = [False]
+    vis: list = []  # visible elements in order
+    p = 0  # the cursor: a position in vis
+    ctr = 2  # the next op's counter; 1 made the text object
+    changes = []  # (ops, ref ops, start op)
+    start = 1
+    ops = [{"action": "makeText", "obj": "_root", "key": "text", "pred": []}]
+    ref = [("makeText", "_root", "text", text, ())]
+
+    def cut():
+        nonlocal ops, ref, start
+        changes.append((ops, ref, start))
+        ops, ref, start = [], [], ctr
+
+    def keystroke(op, ref_op):
+        nonlocal ctr
+        ops.append(op)
+        ref.append(ref_op)
+        ctr += 1
+        if (ctr - 2) % per_change == 0:
+            cut()
+
+    def elem_id(e):
+        return (e_ctr[e], actor)
+
+    pending: list = []
+    for i, length in enumerate(typing):
+        if rng.random() < jump_p:
+            p = rng.randrange(len(vis) + 1)
+        ref_e = vis[p - 1] if p else 0
+        new = []
+        for _ in range(length):
+            e = len(e_ctr)
+            e_ctr.append(ctr)
+            nxt.append(nxt[ref_e])
+            dead.append(False)
+            nxt[ref_e] = e
+            ch = rng.choice("abcdefghijklmnopqrstuvwxyz     ")
+            keystroke({"action": "set", "obj": text_id,
+                       "elemId": opid(*elem_id(ref_e)) if ref_e else "_head",
+                       "insert": True, "value": ch, "pred": []},
+                      ("ins", text_id, elem_id(ref_e) if ref_e else HEAD,
+                       (ctr, actor), ch))
+            new.append(e)
+            ref_e = e
+        vis[p:p] = new
+        p += length
+        pending.extend(after.get(i, ()))
+        while pending and pending[0] <= len(vis):
+            length = pending.pop(0)
+            if rng.random() < jump_p or p < length:
+                p = rng.randrange(length, len(vis) + 1)
+            for e in vis[p - length:p][::-1]:
+                eid = opid(*elem_id(e))
+                dead[e] = True
+                keystroke({"action": "del", "obj": text_id, "elemId": eid,
+                           "pred": [eid]},
+                          ("del", text_id, elem_id(e), (ctr, actor)))
+            del vis[p - length:p]
+            p -= length
+    if ops:
+        cut()
+
+    bufs, refs, deps = [], [], []
+    for seq, (ops, ref, start) in enumerate(changes, 1):
+        buf = encode_change({"actor": actor, "seq": seq, "startOp": start,
+                             "time": 0, "deps": deps, "ops": ops})
+        deps = [change_hash(buf)]
+        bufs.append(buf)
+        refs.append(ref)
+    elems, deleted = [], []
+    e = nxt[0]
+    while e >= 0:
+        if dead[e]:
+            deleted.append(len(elems))
+        elems.append(elem_id(e))
+        e = nxt[e]
+    return bufs, refs, {"max_op": keys + 1, "head": deps[0], "actor": actor,
+                        "text": text, "elems": elems, "deleted": deleted}
+
+
 # ---------------------------------------------------------------------- #
 # per-document generation
 
@@ -317,7 +461,9 @@ class DocGen:
             self.e_actor = np.full(len(elems), -1, np.int32)
             self.e_seq = np.zeros(len(elems), np.int32)
             self.e_del = np.full((len(elems), self.n), 1 << 30, np.int32)
-            self.order = list(range(len(elems)))
+            # deleted in the preload: hidden from every view
+            self.e_del[template.get("deleted", [])] = 0
+            self.order = np.arange(len(elems), dtype=np.int64)  # RGA order
             self.cursor = [-1] * self.n  # element index, -1 = head
 
     # -- views ---------------------------------------------------------- #
@@ -388,26 +534,28 @@ class DocGen:
     # -- text ops ------------------------------------------------------- #
 
     def _text_visible(self, view):
+        """The elements `view` sees, in order, and a mask over element
+        indices of the same (array work only, no step per element)."""
         n = len(self.e_id)
         v = np.asarray(view, np.int32)
         act, seq = self.e_actor[:n], self.e_seq[:n]
         ok = (act < 0) | (v[np.maximum(act, 0)] >= seq)
         ok &= ~(self.e_del[:n] <= v[None, :]).any(axis=1)
-        order = np.asarray(self.order, np.int64)
-        return order[ok[order]]
+        return self.order[ok[self.order]], ok
 
     def _insert_after(self, ref_idx, new_idx, new_id):
         """RGA placement: after the reference, past every following
         element with a greater opId (those and their descendants sort
         first)."""
-        pos = 0 if ref_idx < 0 else self.order.index(ref_idx) + 1
+        pos = (0 if ref_idx < 0
+               else int(np.flatnonzero(self.order == ref_idx)[0]) + 1)
         key = (new_id[0], new_id[1])
         while pos < len(self.order):
             other = self.e_id[self.order[pos]]
             if (other[0], other[1]) < key:
                 break
             pos += 1
-        self.order.insert(pos, new_idx)
+        self.order = np.insert(self.order, pos, new_idx)
 
     def _add_elem(self, a, s, eid):
         idx = len(self.e_id)
@@ -424,10 +572,9 @@ class DocGen:
 
     def _text_ops(self, a, s, ctr, view, kind, length, jump_p):
         rng, ops, ref = self.rng, [], []
-        vis = self._text_visible(view)
-        pos_of = {int(e): i for i, e in enumerate(vis)}
+        vis, seen = self._text_visible(view)
         cur = self.cursor[a]
-        if cur >= 0 and cur not in pos_of:
+        if cur >= 0 and not seen[cur]:
             cur = None  # deleted under the cursor: jump
         if cur is None or rng.random() < jump_p:
             cur = -1
@@ -435,7 +582,7 @@ class DocGen:
             if pos:
                 cur = int(vis[pos - 1])
         if kind == "del":
-            p = pos_of[cur] + 1 if cur >= 0 else 0
+            p = int(np.flatnonzero(vis == cur)[0]) + 1 if cur >= 0 else 0
             if p < length:  # too near the start to backspace: jump
                 p = rng.randrange(length, len(vis) + 1)
             for e in vis[p - length:p][::-1]:
@@ -536,10 +683,16 @@ class DocGen:
 def generate_doc(task):
     """One document's set-up joins and run changes (a worker's unit).
     `task` is (config, traffic, seed, doc, template state, entries, local
-    actors to join) with entries [(plan index, due, actor, kind, length)]. Returns (doc, joins
-    [(buffer, ref ops)], changes [(plan index, buffer, ref ops, n_ops,
-    actor id, seq)], device rows the doc grows by)."""
+    actors to join) with entries [(plan index, due, actor, kind, length)];
+    a state of None makes the document's own `trace_history` first.
+    Returns (doc, joins [(buffer, ref ops)], changes [(plan index, buffer,
+    ref ops, n_ops, actor id, seq)], device rows the doc grows by, preload
+    (buffers, ref ops per change) or None where the template's serves)."""
     config, traffic, seed, doc, state, entries, join = task
+    preload = None
+    if state is None:
+        bufs, refs, state = trace_history(config, seed, doc)
+        preload = (bufs, refs)
     gen = DocGen(config, traffic, seed, doc, state)
     joins = []
     for a in join:
@@ -549,4 +702,4 @@ def generate_doc(task):
     for i, due, a, kind, length in entries:
         buf, _h, ref, nops = gen.change(due, a, kind, length)
         out.append((i, buf, ref, nops, gen.actors[a], gen.seq[a]))
-    return doc, joins, out, gen.rows
+    return doc, joins, out, gen.rows, preload

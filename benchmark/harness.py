@@ -114,10 +114,18 @@ class Traffic:
         plan, _scramble = generator.schedule(
             config, traffic, rate, warmup_s, seconds, seed,
             cfg["cell"]["batching"].get("docs", 0))
-        self.templates = (generator.map_templates(config) if kind == "map"
-                          else generator.text_templates(config))
-        ntpl = len(self.templates)
-        self.tpl_of = [d % ntpl for d in range(self.docs)]
+        # a preload of each document's own is made with its traffic, in
+        # the worker; shared templates here, once
+        own = config["preload"].get("kind") == "trace"
+        if own:
+            self.templates = [None] * self.docs
+            self.tpl_of = list(range(self.docs))
+        else:
+            self.templates = (generator.map_templates(config)
+                              if kind == "map"
+                              else generator.text_templates(config))
+            ntpl = len(self.templates)
+            self.tpl_of = [d % ntpl for d in range(self.docs)]
         entries: dict[int, list] = {}
         for i, (due, doc, a, k, length) in enumerate(plan):
             entries.setdefault(doc, []).append((i, due, a, k, length))
@@ -152,16 +160,17 @@ class Traffic:
                     joined.add(j)
                     joins.setdefault(d, []).append(a)
 
-        def task(d, state=None):
+        def task(d):
             return (config, traffic, seed, d,
-                    state or self.templates[self.tpl_of[d]][2], entries[d],
-                    joins.get(d, []))
+                    None if own else self.templates[self.tpl_of[d]][2],
+                    entries.get(d, []), joins.get(d, []))
 
-        tasks = [task(d) for d in self.touched]
-        if workers > 1 and len(tasks) > 64:
+        tasks = [task(d) for d in (range(self.docs) if own else self.touched)]
+        if workers > 1 and (own or len(tasks) > 64):
             import multiprocessing
 
-            with multiprocessing.get_context("spawn").Pool(workers) as pool:
+            with multiprocessing.get_context("spawn").Pool(
+                    min(workers, len(tasks))) as pool:
                 for done in pool.imap_unordered(
                         generator.generate_doc, tasks,
                         chunksize=max(1, len(tasks) // (4 * workers))):
@@ -171,7 +180,9 @@ class Traffic:
                 self._take(generator.generate_doc(t))
 
     def _take(self, done):
-        d, joins, changes, rows = done
+        d, joins, changes, rows, preload = done
+        if preload is not None:
+            self.templates[d] = preload + (None,)
         bufs, refs = self.extra[d]
         self.extra[d] = (bufs + [b for b, _ in joins],
                          refs + [r for _, r in joins])
@@ -394,16 +405,26 @@ def check(traffic: Traffic, rec: Recorder, preload_patches, final_patches,
     for i in range(len(traffic.due)):
         by_doc.setdefault(int(traffic.doc[i]), []).append(i)
     delivery_of = rec.delivery_of
+    users: dict[int, int] = {}  # template -> touched documents it serves
+    for d in traffic.touched:
+        users[traffic.tpl_of[d]] = users.get(traffic.tpl_of[d], 0) + 1
     tpl_ref = {}
+
+    def preloaded(t):
+        base = reference.RefDoc()
+        for ops in traffic.templates[t][1]:
+            base.apply(ops)
+        return base
+
     patches, bad_patches, bad_final = 0, 0, 0
     for d in traffic.touched:
         t = traffic.tpl_of[d]
-        if t not in tpl_ref:
-            base = reference.RefDoc()
-            for ops in traffic.templates[t][1]:
-                base.apply(ops)
-            tpl_ref[t] = base
-        ref = tpl_ref[t].copy()
+        if users[t] == 1:  # the template serves this document alone
+            ref = preloaded(t)
+        else:
+            if t not in tpl_ref:
+                tpl_ref[t] = preloaded(t)
+            ref = tpl_ref[t].copy()
         touched: set = set()
         for ops in traffic.extra[d][1]:
             ref.apply(ops)

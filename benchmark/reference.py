@@ -8,7 +8,9 @@ tuples with Automerge's semantics, written out directly:
   its initial value plus every ``inc`` that names it;
 - a text object is an RGA: an insert goes after its reference element,
   past every following element with a greater opId (Lamport order:
-  counter, then actor id); a ``del`` hides the element it names.
+  counter, then actor id); a ``del`` hides the element it names. The
+  sequence is kept in blocks with a map from element id to its entry
+  (``_Text``), so an op finds its place without a scan from the start.
 
 ``PatchDoc`` applies the program's reference-format patches (``props``
 per key and opId, text ``edits``: insert, multi-insert, update, remove)
@@ -19,9 +21,120 @@ state exactly.
 """
 from __future__ import annotations
 
+from itertools import islice
+from operator import attrgetter
+
+BLOCK = 128  # a text block splits in two past this many elements
+_VISIBLE, _DELETED = attrgetter("visible"), attrgetter("deleted")
+
 
 def _oid(op):
     return f"{op[0]}@{op[1]}"
+
+
+class _Elem:
+    __slots__ = ("id", "value", "deleted", "block")
+
+    def __init__(self, id_, value, deleted, block):
+        self.id, self.value, self.deleted, self.block = (id_, value, deleted,
+                                                         block)
+
+
+class _Block:
+    """A run of the sequence: its entries, how many are visible, and the
+    block after it."""
+    __slots__ = ("elems", "visible", "next")
+
+    def __init__(self, elems, visible, next_):
+        self.elems, self.visible, self.next = elems, visible, next_
+
+
+class _Text:
+    """One text object's RGA. The whole sequence, deleted elements
+    included, is `blocks` in order; `by_id` finds an element's entry, and
+    the entry its block. Once `visible()` has been asked for, the visible
+    sequence is kept up to date op by op."""
+
+    def __init__(self):
+        self.blocks = [_Block([], 0, None)]
+        self.by_id = {}
+        self.seq = None  # [(opid str, value)] of the visible elements
+
+    def insert(self, after, me, value) -> None:
+        key = (me[0], me[1])
+        if after is None:
+            blk, i = self.blocks[0], 0
+        else:
+            rec = self.by_id[after]
+            blk = rec.block
+            i = blk.elems.index(rec) + 1
+        # past every following element with a greater opId
+        while True:
+            elems = blk.elems
+            while i < len(elems) and elems[i].id > key:
+                i += 1
+            if i < len(elems) or blk.next is None or not (
+                    blk.next.elems[0].id > key):
+                break
+            blk, i = blk.next, 0
+        if self.seq is not None:
+            self.seq.insert(self._rank(blk, i), (_oid(key), value))
+        rec = _Elem(key, value, False, blk)
+        self.by_id[key] = rec
+        blk.elems.insert(i, rec)
+        blk.visible += 1
+        if len(blk.elems) > BLOCK:
+            self._split(blk)
+
+    def delete(self, elem) -> None:
+        rec = self.by_id.get(elem)
+        if rec is None:
+            raise KeyError(f"del of unknown element {elem}")
+        if rec.deleted:
+            return
+        if self.seq is not None:
+            del self.seq[self._rank(rec.block, rec.block.elems.index(rec))]
+        rec.deleted = True
+        rec.block.visible -= 1
+
+    def _rank(self, blk, i) -> int:
+        """Visible elements before position i of `blk` (C-level sums, no
+        Python step per block or element)."""
+        before = islice(self.blocks, self.blocks.index(blk))
+        return (sum(map(_VISIBLE, before)) + i
+                - sum(map(_DELETED, islice(blk.elems, i))))
+
+    def _split(self, blk) -> None:
+        half = len(blk.elems) // 2
+        new = _Block(blk.elems[half:], 0, blk.next)
+        del blk.elems[half:]
+        for e in new.elems:
+            e.block = new
+        new.visible = sum(not e.deleted for e in new.elems)
+        blk.visible -= new.visible
+        blk.next = new
+        self.blocks.insert(self.blocks.index(blk) + 1, new)
+
+    def visible(self) -> list:
+        if self.seq is None:
+            self.seq = [(_oid(e.id), e.value) for b in self.blocks
+                        for e in b.elems if not e.deleted]
+        return self.seq
+
+    def copy(self) -> "_Text":
+        out = _Text()
+        out.blocks = []
+        for b in self.blocks:
+            nb = _Block([], b.visible, None)
+            for e in b.elems:
+                rec = _Elem(e.id, e.value, e.deleted, nb)
+                nb.elems.append(rec)
+                out.by_id[e.id] = rec
+            if out.blocks:
+                out.blocks[-1].next = nb
+            out.blocks.append(nb)
+        out.seq = None if self.seq is None else list(self.seq)
+        return out
 
 
 class RefDoc:
@@ -30,7 +143,7 @@ class RefDoc:
     def __init__(self):
         self.maps = {"_root": {}}  # obj -> key -> {opid str: entry}
         self.counters = {}  # counter opid str -> value
-        self.texts = {}  # obj -> [[opid tuple, value, deleted]]
+        self.texts = {}  # obj -> _Text
 
     def apply(self, ops) -> None:
         for op in ops:
@@ -48,18 +161,13 @@ class RefDoc:
             elif kind == "makeText":
                 _, obj, key, me, pred = op
                 self._put(obj, key, me, pred, ("o", _oid(me), "text"))
-                self.texts[_oid(me)] = []
+                self.texts[_oid(me)] = _Text()
             elif kind == "ins":
                 _, obj, after, me, value = op
-                self._insert(self.texts[obj], after, me, value)
+                self.texts[obj].insert(after, me, value)
             elif kind == "del":
                 _, obj, elem, _me = op
-                for e in self.texts[obj]:
-                    if e[0] == elem:
-                        e[2] = True
-                        break
-                else:
-                    raise KeyError(f"del of unknown element {elem}")
+                self.texts[obj].delete(elem)
             else:
                 raise ValueError(f"unknown op {kind!r}")
 
@@ -69,39 +177,28 @@ class RefDoc:
             slot.pop(_oid(p), None)
         slot[_oid(me)] = entry
 
-    @staticmethod
-    def _insert(elems, after, me, value):
-        if after is None:
-            pos = 0
-        else:
-            pos = next(i for i, e in enumerate(elems) if e[0] == after) + 1
-        key = (me[0], me[1])
-        while pos < len(elems) and (elems[pos][0][0], elems[pos][0][1]) > key:
-            pos += 1
-        elems.insert(pos, [me, value, False])
-
     def copy(self) -> "RefDoc":
         out = RefDoc()
         out.maps = {o: {k: dict(v) for k, v in m.items()}
                     for o, m in self.maps.items()}
         out.counters = dict(self.counters)
-        out.texts = {o: [list(e) for e in t] for o, t in self.texts.items()}
+        out.texts = {o: t.copy() for o, t in self.texts.items()}
         return out
+
+    def values(self, obj, key) -> dict:
+        """The visible ops under `key` of map `obj`, each with its value (a
+        counter: its current total); {} where none is."""
+        return {op: (("v", self.counters[op], "counter")
+                     if e[0] == "v" and e[2] == "counter" else e)
+                for op, e in self.maps[obj].get(key, {}).items()}
 
     def state(self) -> dict:
         out = {}
         for obj, m in self.maps.items():
-            rendered = {}
-            for key, slot in m.items():
-                if not slot:
-                    continue
-                rendered[key] = {
-                    op: (("v", self.counters[op], "counter")
-                         if e[0] == "v" and e[2] == "counter" else e)
-                    for op, e in slot.items()}
-            out[obj] = rendered
-        for obj, elems in self.texts.items():
-            out[obj] = [(_oid(e[0]), e[1]) for e in elems if not e[2]]
+            out[obj] = {key: self.values(obj, key) for key, slot in m.items()
+                        if slot}
+        for obj, text in self.texts.items():
+            out[obj] = list(text.visible())
         return out
 
 
@@ -191,17 +288,16 @@ def patch_agrees(diffs, got: PatchDoc, ref: RefDoc, touched: set) -> bool:
       the key was deleted, so it is right only where the reference holds
       nothing there.
     - Every text object, rebuilt from the patches so far (`got`), equals
-      the reference's visible sequence exactly.
+      the reference's visible sequence exactly. Both sides keep that
+      sequence up to date as they go, so this is one list comparison.
     """
     props = diffs.get("props", {})
     if not touched <= set(props):
         return False
-    want = ref.state()
-    root = want["_root"]
     for key, values in props.items():
         mine = {op: _leaf(v) for op, v in values.items()}
-        if mine != root.get(key, {}):
+        if mine != ref.values("_root", key):
             return False
     got.apply(diffs)
-    mine = got.state()
-    return all(mine.get(obj) == want[obj] for obj in ref.texts)
+    return all(got.texts.get(obj) == text.visible()
+               for obj, text in ref.texts.items())

@@ -25,15 +25,28 @@ SMALL = {
                                                  "max_wait_s": 0.25}},
 }
 LISTED = ["text-512.typing-steady"]
+# a preload of each document's own (the trace kind), on the same cell
+TRACE = ("text-512.typing-steady", dict(
+    SMALL["text-512.typing-steady"], **{
+        "config.docs": 3, "cell.rate_per_s": 8, "config.capacity": 8192,
+        "config.preload": {"kind": "trace", "keystrokes": 6000,
+                           "deletions": 1789, "final_chars": 2422,
+                           "keystrokes_per_change": 1000,
+                           "typing_run_mean": 8, "backspace_run_mean": 4,
+                           "jump_p": 0.1, "rows": 6001},
+        "cell.batching": {"policy": "docs", "docs": 2, "max_wait_s": 0.25}}))
+CASES = {cell: (cell, SMALL[cell]) for cell in LISTED}
+CASES["trace-preload"] = TRACE
 
 
 def run(cell, fault=None, seed=2**33 + 3):
-    return harness.run_cell(cell, seed, 2.0, False, time.perf_counter(),
+    name, overrides = CASES[cell]
+    return harness.run_cell(name, seed, 2.0, False, time.perf_counter(),
                             require_chip=False, fault=fault,
-                            overrides=SMALL[cell])
+                            overrides=overrides)
 
 
-@pytest.mark.parametrize("cell", LISTED)
+@pytest.mark.parametrize("cell", list(CASES))
 def test_sound_run_is_correct(cell):
     result = run(cell)
     assert result["correct"], result["checks"]
@@ -42,7 +55,7 @@ def test_sound_run_is_correct(cell):
 
 
 @pytest.mark.parametrize("fault", harness.FAULTS)
-@pytest.mark.parametrize("cell", LISTED)
+@pytest.mark.parametrize("cell", list(CASES))
 def test_fault_is_caught(cell, fault):
     result = run(cell, fault)
     assert not result["correct"], (fault, result["checks"])

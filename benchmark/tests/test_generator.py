@@ -1,5 +1,8 @@
 """The generator: deterministic by seed, with the stated shape."""
+import hashlib
+
 import numpy as np
+import pytest
 
 from benchmark import generator, harness, reference
 
@@ -108,3 +111,138 @@ def test_text_reference_matches_the_typists_model():
             ref.apply(t.ref[i])
         (text,) = ref.texts
         assert len(ref.state()[text]) > 200  # 256 preloaded, 30% deletes
+
+
+TRACE = {"kind": "trace", "keystrokes": 259778, "deletions": 77463,
+         "final_chars": 104852, "keystrokes_per_change": 1000,
+         "typing_run_mean": 8, "backspace_run_mean": 4, "jump_p": 0.1,
+         "rows": 259779}
+
+
+def trace_preload(keystrokes, deletions):
+    return dict(TRACE, keystrokes=keystrokes, deletions=deletions,
+                final_chars=keystrokes - 2 * deletions)
+
+
+def trace_ops(refs):
+    return [op for ops in refs for op in ops]
+
+
+def test_trace_preload_has_the_traces_counts_at_full_size():
+    from automerge_tpu.columnar import decode_change
+
+    bufs, refs, state = generator.trace_history({"preload": TRACE},
+                                                2**33 + 31, 0)
+    ops = trace_ops(refs)
+    kinds = [op[0] for op in ops]
+    assert kinds.count("makeText") == 1
+    assert kinds.count("ins") + kinds.count("del") == 259778
+    assert kinds.count("del") == 77463
+    assert len(state["elems"]) == 182315
+    assert len(state["deleted"]) == 77463
+    assert len(bufs) == len(refs) == 260
+    assert [len(r) for r in refs] == [1001] + [1000] * 258 + [778]
+    ref = reference.RefDoc()
+    for r in refs:
+        ref.apply(r)
+    (text,) = ref.texts
+    visible = ref.texts[text].visible()
+    assert len(visible) == 104852
+    # the state's RGA order and deleted set are the reference's
+    dead = set(state["deleted"])
+    assert [f"{c}@{a}" for i, (c, a) in enumerate(state["elems"])
+            if i not in dead] == [op for op, _v in visible]
+    last = decode_change(bufs[-1])
+    assert last["seq"] == 260 and last["startOp"] == 259002
+    assert state["max_op"] == 259779 and state["head"] == last["hash"]
+
+
+def test_trace_preload_is_deterministic_by_seed_and_distinct_by_doc():
+    cfg = {"preload": trace_preload(6000, 1800)}
+    a = generator.trace_history(cfg, 2**33 + 41, 0)
+    assert a == generator.trace_history(cfg, 2**33 + 41, 0)
+    other_seed = generator.trace_history(cfg, 2**33 + 43, 0)
+    other_doc = generator.trace_history(cfg, 2**33 + 41, 1)
+    assert a[0] != other_seed[0]
+
+    def ids(history):
+        return {op[3] for op in trace_ops(history[1])
+                if op[0] in ("ins", "makeText")}
+
+    assert not ids(a) & ids(other_doc)
+    assert len(ids(a)) == 6000 - 1800 + 1
+
+
+def test_trace_preload_refuses_counts_that_disagree():
+    with pytest.raises(ValueError):
+        generator.trace_history(
+            {"preload": dict(trace_preload(6000, 1800), final_chars=2401)},
+            1, 0)
+
+
+def test_window_starts_from_the_trace_preloads_state():
+    """A document's own preload is made in the worker; its deleted
+    elements are in no view, and the window's typists act on visible
+    elements only."""
+    c = cfg("text-512.typing-steady", 3)
+    c["config"]["preload"] = trace_preload(6000, 1800)
+    c["config"]["capacity"] = 8192
+    c["cell"] = dict(c["cell"], batching={"policy": "docs", "docs": 2,
+                                             "max_wait_s": 0.25})
+    t = harness.Traffic(c, 40, 1, 4, 2**33 + 47, workers=2)
+    assert t.tpl_of == [0, 1, 2]
+    for d in range(3):
+        pre = trace_ops(t.templates[d][1])
+        dead = {op[2] for op in pre if op[0] == "del"}
+        assert dead
+        window = trace_ops(t.extra[d][1]) + trace_ops(
+            t.ref[i] for i in np.flatnonzero(t.doc == d))
+        assert window
+        for op in window:
+            target = op[2]
+            assert target not in dead, op
+        ref = reference.RefDoc()
+        for ops in t.preload_refs(d):
+            ref.apply(ops)
+        for i in np.flatnonzero(t.doc == d):
+            ref.apply(t.ref[i])
+        (text,) = ref.texts
+        assert len(ref.texts[text].visible()) > 1800
+
+
+def traffic_hash(t):
+    h = hashlib.sha256()
+    for bufs in t.preload():
+        for b in bufs:
+            h.update(b)
+    for d in range(t.docs):
+        h.update(repr(t.preload_refs(d)).encode())
+    for b in t.buf:
+        h.update(b)
+    h.update(repr(t.ref).encode())
+    h.update(t.due.tobytes())
+    h.update(t.doc.tobytes())
+    return h.hexdigest()
+
+
+# the text-512 traffic's hash at a reduced size, as the harness made it
+# before trace preloads were added; the second case goes through the pool
+TEXT_512_HASHES = [
+    (16, 4, 256, 60, 1,
+     "54a58a2152511a1fc653242c397fa26fbe22ea03724468bae12750e204c32231"),
+    (96, 8, 128, 200, 2,
+     "f54c3dc368842132df33e411e1cff81423a460c9dd778a5e11924e8e55d54a20"),
+]
+
+
+@pytest.mark.parametrize("docs,templates,chars,rate,workers,want",
+                         TEXT_512_HASHES)
+def test_text_512_traffic_is_unchanged(docs, templates, chars, rate, workers,
+                                       want):
+    c = cfg("text-512.typing-steady", docs)
+    c["config"]["preload"] = dict(c["config"]["preload"], templates=templates,
+                                  chars=chars, rows=chars + 1)
+    c["cell"] = dict(c["cell"], batching={"policy": "docs", "docs": 8,
+                                             "max_wait_s": 0.25})
+    t = harness.Traffic(c, rate, 1, 4, 2**33 + 29, workers=workers)
+    assert traffic_hash(t) == want
